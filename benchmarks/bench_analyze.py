@@ -16,25 +16,25 @@ before any compile, and configs it proves over-budget are answered
   least match the predictor's 5-of-96 on this trace, and the winner is
   *identical* to the unpruned search (a proof, unlike a prediction,
   carries no survivor hedge — so winner identity must hold exactly).
-* ``analyze_clean_registry`` — ``python -m repro.analyze --strict`` in a
-  fresh interpreter (the CI gate verbatim: earlier bench sections
-  register scratch kernels into this process's registry, so the shipped
-  registry must be judged in isolation) must exit 0 with zero error and
-  zero warning findings.
+* ``analyze_clean_registry`` — the ``python -m repro.analyze --strict``
+  gate, run in this process over the kernels the package ships (earlier
+  bench sections register scratch kernels into this process's registry,
+  so those are left out), must find zero errors and zero warnings.  It
+  runs in-process because a child interpreter could not reach a chip
+  this process already holds.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
-import subprocess
-import sys
 
-from repro.analyze import proven_checker
+from repro.analyze import analyze_registry, proven_checker
 from repro.core import (EngineConfig, EvaluationEngine, KernelSpec,
                         TPUAnalyticalEvaluator, make_strategy)
 from repro.core.profiles import TPU_V3
+from repro.core.registry import REGISTRY
 from repro.kernels.matmul.ops import GEMM
+from repro.tune import sharding_autotune  # noqa: F401 — registers sharding_cell
 
 from .common import emit
 
@@ -87,21 +87,16 @@ def main() -> None:
          compiles=prov_s["compile_calls"],
          engine=prov_s)
 
-    # -- registry hygiene: the --strict CI gate, fresh interpreter ---------
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.analyze", "--strict", "--quiet"],
-        capture_output=True, text=True)
-    try:
-        counts = json.loads(proc.stdout)["counts"]
-    except (json.JSONDecodeError, KeyError, TypeError):
-        counts = None
-    clean = proc.returncode == 0 and counts is not None
+    # -- registry hygiene: the --strict CI gate over the shipped kernels --
+    shipped = [n for n in REGISTRY.names()
+               if REGISTRY.get(n).build.__module__.startswith("repro.")]
+    report = analyze_registry(kernels=shipped)
+    counts = report.counts()
+    clean = report.exit_code(strict=True) == 0
     emit("analyze/analyze_clean_registry", 0.0,
          (f"shipped registry clean under --strict: "
           f"{counts['info']} info advisories, 0 errors, 0 warnings"
-          if clean else
-          f"strict gate failed (exit {proc.returncode}): "
-          f"counts={counts} stderr={proc.stderr.strip()[:300]}"),
+          if clean else f"strict gate failed: counts={counts}"),
          status="ok" if clean else "error")
 
 

@@ -55,14 +55,22 @@ def _short_requests(cfg, n: int, seed: int) -> List[Request]:
 
 
 def _timed_run(engine, requests) -> Tuple[int, List[float]]:
-    """Serve ``requests``; return (finished, per-step wall seconds)."""
-    for r in requests:
-        engine.submit(r)
-    stamps: List[float] = []
-    done = engine.run(on_step=lambda e, s: stamps.append(time.perf_counter()))
-    stamps.append(time.perf_counter())
-    durs = [b - a for a, b in zip(stamps, stamps[1:])]
-    return sum(1 for r in done if r.done), durs
+    """Serve ``requests``; return (finished, per-step wall seconds).
+
+    Requests go in waves of SLOTS, one batch each: slots share one decode
+    position, and refilling mid-batch would run the SMALL bucket past its
+    KV rows."""
+    finished, durs = 0, []
+    for i in range(0, len(requests), SLOTS):
+        for r in requests[i:i + SLOTS]:
+            engine.submit(r)
+        stamps: List[float] = []
+        done = engine.run(
+            on_step=lambda e, s: stamps.append(time.perf_counter()))
+        stamps.append(time.perf_counter())
+        durs += [b - a for a, b in zip(stamps, stamps[1:])]
+        finished += sum(1 for r in done if r.done)
+    return finished, durs
 
 
 def _p99_us(durs: List[float]) -> float:

@@ -28,6 +28,8 @@ import time
 import traceback
 from typing import Any, Callable, Dict
 
+from repro.core.envknobs import configure_compile_cache
+
 from . import common
 
 
@@ -66,6 +68,7 @@ def write_payload(name: str, payload: Dict[str, Any]) -> str:
 
 
 def main() -> None:
+    configure_compile_cache()
     only = os.environ.get("REPRO_BENCH_ONLY", "")
     wanted = set(only.split(",")) if only else None
     from . import (bench_analyze, bench_artifacts, bench_conv, bench_dtune,

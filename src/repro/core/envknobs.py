@@ -27,12 +27,16 @@ plus the static analyzer's ``REPRO_ANALYZE`` (run the pre-search space
 audit + proven-infeasible pruning by default) and
 ``REPRO_ANALYZE_STRICT`` (escalate error findings to a raised
 ValueError before any search runs).
+
+:func:`configure_compile_cache` places JAX's persistent compilation cache
+for the entry points; it is never called on import.
 """
 
 from __future__ import annotations
 
 import logging
 import os
+from pathlib import Path
 from typing import Iterable, Optional
 
 log = logging.getLogger("repro.envknobs")
@@ -98,3 +102,24 @@ def env_str(name: str, default: Optional[str] = None, *,
                     name, raw, sorted(set(choices)), default)
         return default
     return raw
+
+
+#: the checkout this package runs from (<root>/src/repro/core/envknobs.py)
+_CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; entry points call this.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left as it is.  Otherwise the cache goes to ``<checkout>/.jax_cache``:
+    a fixed path, so a later run in the same checkout finds it again.
+    Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(_CHECKOUT_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -228,6 +228,28 @@ class Evaluator:
         return _obj
 
 
+def on_tpu() -> bool:
+    """Whether JAX's default backend is a TPU (initializes the backend)."""
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Whether Pallas kernels build in interpret mode.
+
+    None means: interpret on a host backend, compile for the chip on a
+    TPU.  Asking for interpret mode on a TPU raises — a kernel run through
+    the interpreter there would be timed and cached as if it were the
+    compiled kernel."""
+    tpu = on_tpu()
+    if interpret is None:
+        return not tpu
+    if interpret and tpu:
+        raise ValueError(
+            "interpret=True on a TPU backend: the Pallas interpreter is a "
+            "host-side debugging path, not the kernel the chip runs")
+    return bool(interpret)
+
+
 def _failed(err: Exception | str, compile_s: float = 0.0) -> Measurement:
     return Measurement(time_s=math.inf, ok=False, compile_s=compile_s,
                        error=str(err)[:500])
@@ -389,8 +411,6 @@ class CostModelEvaluator(Evaluator):
             try:
                 compiled = lowered.compile()
                 cost = compiled.cost_analysis() or {}
-                if isinstance(cost, (list, tuple)):  # older jax: dict/device
-                    cost = cost[0] if cost else {}
             except Exception as e:  # noqa: BLE001
                 raise CompileError(f"{type(e).__name__}: {e}") from e
             coll = 0.0

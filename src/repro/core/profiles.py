@@ -118,6 +118,40 @@ PROFILES: Dict[str, DeviceProfile] = {
     p.name: p for p in (TPU_V5E, TPU_V4, TPU_V5P, TPU_V3)
 }
 
+#: ``device_kind`` as JAX reports it on an attached chip -> its profile.
+#: Only chips this code has run on are listed; the other profiles are
+#: modeled and have no attached-device entry.
+DEVICE_KINDS: Dict[str, DeviceProfile] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def profile_for_kind(device_kind: str) -> DeviceProfile:
+    """The profile of an attached chip, by its ``device_kind``.
+
+    An unknown kind raises: timing a chip against another chip's limits
+    would prune and record under the wrong device."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError as e:
+        raise KeyError(
+            f"no device profile for device_kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_KINDS)}") from e
+
+
+def attached_profile() -> DeviceProfile:
+    """The profile of the chip JAX runs on (its first device).
+
+    Raises on a non-TPU backend, naming the platform, and on a TPU whose
+    ``device_kind`` has no profile."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"no TPU attached: JAX's default platform is {dev.platform!r}")
+    return profile_for_kind(dev.device_kind)
+
 
 def get_profile(name: str) -> DeviceProfile:
     try:

@@ -41,8 +41,9 @@ from .artifacts import ArtifactStore, resolve_store
 from .cache import TuningCache, default_cache
 from .engine import EngineConfig, EvaluationEngine
 from .evaluators import (Evaluator, KernelSpec, Measurement,
-                         TPUAnalyticalEvaluator, WallClockEvaluator)
-from .profiles import DeviceProfile, TPU_V5E
+                         TPUAnalyticalEvaluator, WallClockEvaluator, on_tpu,
+                         resolve_interpret)
+from .profiles import DeviceProfile, TPU_V5E, attached_profile
 from .registry import Shape, TunableKernel, resolve
 from .space import Config, Parameter, SearchSpace
 from .strategies import SearchResult, Strategy, make_strategy
@@ -180,7 +181,7 @@ class Tuner:
                      profile: DeviceProfile = TPU_V5E,
                      cache: Optional[TuningCache] = None,
                      artifact_store: "ArtifactStore | str | None" = None,
-                     interpret: bool = True,
+                     interpret: Optional[bool] = None,
                      extended_space: bool = False) -> "Tuner":
         """Build a ready-to-run Tuner from a :class:`TunableKernel` spec.
 
@@ -190,13 +191,29 @@ class Tuner:
         tuner for a concrete shape is one call.  The fluent
         ``add_parameter``/``add_constraint`` methods still work on the
         result (CLTune-style compatibility layer).
+
+        On a TPU backend the default evaluator times the compiled kernel
+        on the chip, and ``profile`` must describe that chip.  On a host
+        backend it is the kernel's analytical model when one is declared
+        (kernels build in interpret mode there, see
+        :func:`~repro.core.evaluators.resolve_interpret`).
         """
         k = resolve(kernel)
         shape = dict(shape)
+        interpret = resolve_interpret(interpret)
         if evaluator is None:
-            evaluator = (TPUAnalyticalEvaluator(profile=profile)
-                         if k.analytical_model is not None
-                         else WallClockEvaluator())
+            if on_tpu():
+                attached = attached_profile()
+                if profile.name != attached.name:
+                    raise ValueError(
+                        f"profile {profile.name!r} does not describe the "
+                        f"attached chip ({attached.name!r}); device timings "
+                        f"would be recorded under the wrong device")
+                evaluator = WallClockEvaluator()
+            elif k.analytical_model is not None:
+                evaluator = TPUAnalyticalEvaluator(profile=profile)
+            else:
+                evaluator = WallClockEvaluator()
         tuner = cls(evaluator=evaluator, profile=profile, cache=cache,
                     artifact_store=artifact_store)
         tuner.space = k.make_space(shape, extended=extended_space)

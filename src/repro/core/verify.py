@@ -39,13 +39,18 @@ def _leaf_close(a, b, atol: Optional[float], rtol: Optional[float]) -> None:
     da, dr = _TOLS.get(jnp.asarray(a).dtype, (1e-5, 1e-5))
     atol = da if atol is None else atol
     rtol = dr if rtol is None else rtol
-    if not np.allclose(a, b, atol=atol, rtol=rtol, equal_nan=False):
-        err = np.abs(a.astype(np.float64) - b.astype(np.float64))
-        denom = np.maximum(np.abs(b.astype(np.float64)), 1e-30)
+    # normwise: rtol scales with the reference's largest magnitude.  An
+    # output of a long reduction can sit near zero while its rounding error
+    # scales with the terms summed; elementwise, two exact float32
+    # summation orders of a 2048^3 GEMM already disagree on ~0.6% of
+    # elements at rtol 1e-5.
+    a64, b64 = a.astype(np.float64), b.astype(np.float64)
+    err = np.abs(a64 - b64)
+    scale = float(np.abs(b64).max()) if b64.size else 0.0
+    if not np.all(err <= atol + rtol * scale):   # NaN anywhere fails too
         raise VerificationError(
-            f"output mismatch: max_abs_err={err.max():.3e} "
-            f"max_rel_err={(err / denom).max():.3e} "
-            f"(atol={atol}, rtol={rtol})")
+            f"output mismatch: max_abs_err={np.nanmax(err):.3e} "
+            f"max|ref|={scale:.3e} (atol={atol}, rtol={rtol} of max|ref|)")
 
 
 def assert_trees_close(candidate: Any, reference: Any,

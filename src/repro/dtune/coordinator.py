@@ -128,7 +128,7 @@ class DistributedTuner:
                  artifact_store: "ArtifactStore | str | None" = None,
                  budget: Optional[int] = None,
                  engine: "EngineConfig | Mapping[str, Any] | None" = None,
-                 interpret: bool = True,
+                 interpret: Optional[bool] = None,
                  extended_space: Optional[bool] = None,
                  warm_start: "bool | int" = True,
                  seed: int = 0,

@@ -17,13 +17,13 @@ cache file and the coordinator folds those into the shared cache with
 Two drivers run a fleet of specs:
 
 * ``thread`` — in-process pool.  Zero setup cost; right for analytical /
-  cost-model evaluators (pure Python, cheap) and for tests.  Wall-clock
-  measurement in concurrent threads contends for the device, so prefer
-  processes there.
+  cost-model evaluators (pure Python, cheap) and for tests, and the only
+  driver that can time kernels on a TPU, which one process holds.
 * ``process`` — one OS process per worker (``fork`` server where
   available, ``spawn`` otherwise).  True isolation: a worker segfaulting
   in a compiler cannot take the coordinator down; results come back over
-  a queue and caches over the filesystem.
+  a queue and caches over the filesystem.  Refused for device timing on
+  a TPU backend.
 """
 
 from __future__ import annotations
@@ -36,7 +36,8 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Mapping, Optional, Union
 
 from ..core.cache import TuningCache
-from ..core.evaluators import Evaluator, make_evaluator
+from ..core.evaluators import (Evaluator, WallClockEvaluator, make_evaluator,
+                               on_tpu)
 from ..core.profiles import get_profile
 from ..core.registry import resolve
 from ..core.tuner import Tuner
@@ -80,7 +81,7 @@ class WorkerSpec:
     #: EngineConfig kwargs (workers, prune_factor, max_failures, ...);
     #: the runtime stop event is injected separately, never pickled
     engine: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    interpret: bool = True
+    interpret: Optional[bool] = None
     extended_space: bool = False
     #: private cache file this worker records its shard winner into;
     #: None = don't record (results only travel via WorkerResult)
@@ -185,6 +186,18 @@ class TuningWorker:
 
 # -- drivers -------------------------------------------------------------------
 
+def _times_device(spec: EvaluatorSpec) -> bool:
+    """Whether an evaluator spec runs kernels on the device (None is the
+    kernel's default, which on a TPU backend is wall-clock timing)."""
+    if spec is None or isinstance(spec, WallClockEvaluator):
+        return True
+    if isinstance(spec, str):
+        return spec == WallClockEvaluator.name
+    if isinstance(spec, Mapping):
+        return spec.get("name") == WallClockEvaluator.name
+    return False
+
+
 def _process_entry(spec: WorkerSpec, queue: "mp.Queue",
                    stop_event: Optional[Any] = None) -> None:
     """Module-level child entry point (picklable under spawn)."""
@@ -214,6 +227,12 @@ def run_workers(specs: List[WorkerSpec], driver: str = "thread", *,
     if driver != "process":
         raise ValueError(f"unknown dtune driver {driver!r}; "
                          "known: 'thread', 'process'")
+    if any(_times_device(s.evaluator) for s in specs) and on_tpu():
+        raise ValueError(
+            "driver='process' cannot time kernels on a TPU: the chip "
+            "belongs to one process, and this one already holds it, so a "
+            "worker process would fail or hang reaching it; use "
+            "driver='thread' for device timing")
     # fork keeps live registry/evaluator state; spawn is the portable
     # fallback and is why WorkerSpec is all-plain-data
     method = "fork" if "fork" in mp.get_all_start_methods() else "spawn"

@@ -22,13 +22,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.profiles import DeviceProfile
+from ...core.profiles import TPU_V5E, DeviceProfile
 
 Config = Dict[str, Any]
 
 DEFAULT_CONFIG: Config = {"BLOCK_Q": 256, "BLOCK_K": 512}
 
 _NEG = -1e30
+
+#: full float32 products: Mosaic's default for f32 operands is a single
+#: bf16 pass, far from the float32 reference
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -46,7 +50,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     q = q_ref[...].astype(jnp.float32)            # (bq, d)
     k = k_ref[...].astype(jnp.float32)            # (bk, d)
     v = v_ref[...].astype(jnp.float32)            # (bk, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                precision=_PRECISION) * scale
 
     if causal:
         # global positions; query block ends align with KV end (prefix cache)
@@ -62,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     p = jnp.exp(s - m_new)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p, v, preferred_element_type=jnp.float32, precision=_PRECISION)
     m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
@@ -94,8 +99,10 @@ def make_flash_attention(Sq: int, Sk: int, D: int,
         sq=Sq, sk=Sk, bq=bq, bk=bk)
     kwargs: Dict[str, Any] = {}
     if not interpret:
+        # the VMEM budget the static proofs check footprints against
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_V5E.vmem_bytes)
     return pl.pallas_call(
         kernel,
         grid=(Sq // bq, nk),

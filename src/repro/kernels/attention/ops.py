@@ -143,7 +143,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def make_tuner(Sq: int, Sk: int, D: int, *, causal: bool = True,
                evaluator=None, profile: DeviceProfile = TPU_V5E,
-               interpret: bool = True) -> Tuner:
+               interpret: Optional[bool] = None) -> Tuner:
     return Tuner.from_tunable(FLASH_ATTENTION, _shape(Sq, Sk, D, causal),
                               evaluator=evaluator, profile=profile,
                               interpret=interpret)

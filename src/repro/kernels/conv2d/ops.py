@@ -141,7 +141,8 @@ def conv2d(image: jax.Array, filt: jax.Array,
 # ---------------------------------------------------------------------------
 
 def make_tuner(H: int, W: int, Fh: int, Fw: int, *, evaluator=None,
-               profile: DeviceProfile = TPU_V5E, interpret: bool = True,
+               profile: DeviceProfile = TPU_V5E,
+               interpret: Optional[bool] = None,
                extended_space: bool = True) -> Tuner:
     return Tuner.from_tunable(CONV2D, _shape(H, W, Fh, Fw),
                               evaluator=evaluator, profile=profile,

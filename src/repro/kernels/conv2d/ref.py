@@ -23,7 +23,9 @@ def conv2d_reference(image: jnp.ndarray, filt: jnp.ndarray,
         img, ker,
         window_strides=(1, 1),
         padding=((fh // 2, (fh - 1) // 2), (fw // 2, (fw - 1) // 2)),
-        dimension_numbers=("NCHW", "OIHW", "NCHW"))
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        # float32 products even on a TPU (default there: one bf16 pass)
+        precision=lax.Precision.HIGHEST)
     return (weight * out[0, 0]).astype(image.dtype)
 
 

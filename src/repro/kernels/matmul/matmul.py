@@ -29,10 +29,11 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.profiles import DeviceProfile
+from ...core.profiles import TPU_V5E, DeviceProfile
 
 Config = Dict[str, Any]
 
@@ -41,6 +42,11 @@ DEFAULT_CONFIG: Config = {
     "GRID_ORDER": "mn", "INNER_STEPS": 1,
     "ACC_DTYPE": "float32", "ACC_IN_OUTPUT": False, "TRANS_A": False,
 }
+
+
+#: full float32 products: Mosaic's default for f32 operands is a single
+#: bf16 pass (normwise error ~2e-3 at 2048^3, far from f32 accuracy)
+_PRECISION = lax.Precision.HIGHEST
 
 
 def _dtype(name: str):
@@ -64,7 +70,8 @@ def _mm_kernel_scratch(a_ref, b_ref, o_ref, acc_ref, *, nk: int,
         a = a.T                     # block arrives (BK, BM): transpose in VREGs
     b = b_ref[...]
     if inner_steps == 1:
-        acc_ref[...] += jnp.dot(a, b, preferred_element_type=acc_dtype)
+        acc_ref[...] += jnp.dot(a, b, preferred_element_type=acc_dtype,
+                                precision=_PRECISION)
     else:
         # K_wi unroll: split the BK dimension into inner_steps sub-dots.
         # On TPU this shortens MXU dependency chains for small blocks.
@@ -73,7 +80,8 @@ def _mm_kernel_scratch(a_ref, b_ref, o_ref, acc_ref, *, nk: int,
         for s in range(inner_steps):
             acc += jnp.dot(a[:, s * step:(s + 1) * step],
                            b[s * step:(s + 1) * step, :],
-                           preferred_element_type=acc_dtype)
+                           preferred_element_type=acc_dtype,
+                           precision=_PRECISION)
         acc_ref[...] = acc
 
     @pl.when(pl.program_id(2) == nk - 1)
@@ -94,14 +102,16 @@ def _mm_kernel_inplace(a_ref, b_ref, o_ref, *, nk: int, inner_steps: int,
         a = a.T
     b = b_ref[...]
     if inner_steps == 1:
-        o_ref[...] += jnp.dot(a, b, preferred_element_type=acc_dtype)
+        o_ref[...] += jnp.dot(a, b, preferred_element_type=acc_dtype,
+                              precision=_PRECISION)
     else:
         step = a.shape[1] // inner_steps
         acc = o_ref[...]
         for s in range(inner_steps):
             acc += jnp.dot(a[:, s * step:(s + 1) * step],
                            b[s * step:(s + 1) * step, :],
-                           preferred_element_type=acc_dtype)
+                           preferred_element_type=acc_dtype,
+                           precision=_PRECISION)
         o_ref[...] = acc
 
 
@@ -163,9 +173,12 @@ def make_matmul(M: int, N: int, K: int, config: Config | None = None,
         out_shape=out_shape, interpret=interpret)
     if not interpret:
         # M/N grid dims are embarrassingly parallel; K carries the
-        # accumulator dependency.
+        # accumulator dependency.  The VMEM limit is the budget the
+        # static proofs check footprints against (the compiler's own
+        # default scoped limit is far smaller).
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_V5E.vmem_bytes)
 
     if cfg["ACC_IN_OUTPUT"]:
         kernel = functools.partial(_mm_kernel_inplace, **common)

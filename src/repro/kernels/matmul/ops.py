@@ -191,7 +191,8 @@ def matmul(a: jax.Array, b: jax.Array, config: Optional[Dict[str, Any]] = None,
 # ---------------------------------------------------------------------------
 
 def make_tuner(M: int, N: int, K: int, *, evaluator=None,
-               profile: DeviceProfile = TPU_V5E, interpret: bool = True,
+               profile: DeviceProfile = TPU_V5E,
+               interpret: Optional[bool] = None,
                extended_space: bool = False, seed: int = 0) -> Tuner:
     """A ready-to-run Tuner for this GEMM shape (the paper's case study 2)."""
     return Tuner.from_tunable(GEMM, _shape(M, N, K), evaluator=evaluator,

@@ -8,6 +8,7 @@ paper's A^T layout) or M-major.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 
 def gemm_reference(a, b, c=None, *, alpha: float = 1.0, beta: float = 0.0,
@@ -17,8 +18,11 @@ def gemm_reference(a, b, c=None, *, alpha: float = 1.0, beta: float = 0.0,
     a: (M, K) or (K, M) when trans_a; b: (K, N); returns (M, N) in a.dtype.
     """
     lhs = a.T if trans_a else a
+    # HIGHEST: on a TPU the default f32 dot is a single bf16 pass, far
+    # below the float32 accuracy this oracle stands for
     out = jnp.dot(lhs.astype(acc_dtype), b.astype(acc_dtype),
-                  preferred_element_type=acc_dtype)
+                  preferred_element_type=acc_dtype,
+                  precision=lax.Precision.HIGHEST)
     out = alpha * out
     if c is not None and beta != 0.0:
         out = out + beta * c.astype(acc_dtype)

@@ -12,6 +12,9 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.core.envknobs import configure_compile_cache
+from repro.core.evaluators import on_tpu
+from repro.core.profiles import TPU_V5E, attached_profile
 from repro.models.model import init_model
 from repro.serve import Request, ServeEngine
 
@@ -27,10 +30,14 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    configure_compile_cache()
+    # on a TPU, the attached chip's profile (an unknown chip raises); a
+    # host run resolves kernel configs for the v5e target
+    profile = attached_profile() if on_tpu() else TPU_V5E
     cfg = get_config(args.arch, smoke=not args.full)
     params = init_model(cfg, jax.random.PRNGKey(args.seed))
     engine = ServeEngine(cfg, params, slots=args.slots,
-                         max_len=args.max_len)
+                         max_len=args.max_len, profile=profile)
     rng = np.random.default_rng(args.seed)
     for rid in range(args.requests):
         prompt = rng.integers(1, cfg.vocab_size,

@@ -13,6 +13,9 @@ import logging
 
 
 from repro.configs import get_config
+from repro.core.envknobs import configure_compile_cache
+from repro.core.evaluators import on_tpu
+from repro.core.profiles import attached_profile
 from repro.data import DataConfig
 from repro.models.model import RunConfig
 from repro.optim import adamw
@@ -39,6 +42,9 @@ def main():
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    configure_compile_cache()
+    if on_tpu():                  # an unknown chip raises before training
+        logging.info("device profile: %s", attached_profile().name)
     cfg = get_config(args.arch, smoke=not args.full)
     data_cfg = DataConfig(seq_len=args.seq_len,
                           global_batch=args.global_batch,

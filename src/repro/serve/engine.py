@@ -28,7 +28,7 @@ from .. import kernels  # noqa: F401 — populates the tunable registry
 from ..core.cache import (CacheEntry, OBJ_PREFIX, TuningCache, default_cache,
                           normalize_objective, split_key)
 from ..core.envknobs import env_bool, env_str
-from ..core.evaluators import ArrivalTraceEvaluator
+from ..core.evaluators import ArrivalTraceEvaluator, on_tpu
 from ..core.profiles import DeviceProfile, TPU_V5E
 from ..core.registry import (AutotunePolicy, REGISTRY, Resolution,
                              lookup_resolved)
@@ -411,7 +411,20 @@ class ServeEngine:
             self._step_configs = configs
             if on_step is not None:
                 on_step(self, self._steps_total)
+            if not any(self._slot_req):
+                # no slot holds a request, so no KV row is live: a fresh
+                # batch starts writing (and attending) from row 0
+                self._pos = 0
             self._fill_slots()
+            if self._pos >= self.max_len:
+                # every slot writes its KV at the one shared position; past
+                # the last row the write would be clamped onto it silently
+                raise RuntimeError(
+                    f"serve: decode position {self._pos} reached "
+                    f"max_len={self.max_len} with requests in flight; the "
+                    f"position restarts only when every slot is free, so "
+                    f"one continuous batch (refills included) may run at "
+                    f"most max_len steps")
             tokens = self._current_tokens()
             next_tok, self.cache = self._step(self.params, self.cache,
                                               tokens, self._pos)
@@ -547,7 +560,8 @@ class BucketedServeEngine:
     All buckets share one tuning cache and one
     :class:`~repro.serve.online.BackgroundTuner` whose objective defaults
     to ``p99_time`` over a deterministic modeled arrival trace
-    (:func:`modeled_arrival_trace`): the winner recorded for a bucket
+    (:func:`modeled_arrival_trace`; on a TPU, over each config's timed
+    repeats on the chip instead): the winner recorded for a bucket
     must be fast at the tail of the arrivals it actually absorbs, not
     just at its padded bound.  Winners land under objective-scoped cache
     keys and hot-swap into exactly the bucket that watches them —
@@ -616,7 +630,10 @@ class BucketedServeEngine:
                 f"{online_tune!r}")
         if knobs.objective is None and objective is not None:
             knobs = dataclasses.replace(knobs, objective=objective)
-        if knobs.evaluator_factory is None:
+        # the modeled trace prices configs with the analytical model; on a
+        # TPU the retune times each config on the chip instead (p99 over
+        # its timed repeats at the bucket's geometry)
+        if knobs.evaluator_factory is None and not on_tpu():
             knobs = dataclasses.replace(
                 knobs, evaluator_factory=trace_evaluator_factory(
                     arrivals=trace_arrivals, seed=knobs.seed))

@@ -165,7 +165,8 @@ class OnlineTuneConfig:
     #: cache and reused by every subsequent job, so all retunes rank with
     #: one model trained from the fleet's merged history.
     predictor: Optional[Any] = None
-    interpret: bool = True
+    #: None = interpret on a host backend, compiled on a TPU
+    interpret: Optional[bool] = None
     seed: int = 0
     #: refuse new jobs beyond this many queued-but-unstarted ones
     max_pending: int = 8

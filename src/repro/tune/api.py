@@ -66,7 +66,7 @@ def tune_kernel(kernel: "TunableKernel | str", shape: Shape, *,
                 artifact_store: "ArtifactStore | str | None" = None,
                 record: bool = True,
                 seed: int = 0,
-                interpret: bool = True,
+                interpret: Optional[bool] = None,
                 extended_space: Optional[bool] = None,
                 engine: "EngineConfig | Dict[str, Any] | None" = None,
                 warm_start: "bool | int | None" = None,
@@ -79,6 +79,8 @@ def tune_kernel(kernel: "TunableKernel | str", shape: Shape, *,
 
     Strategy and budget default to the kernel's declared ``defaults`` and
     fall back to annealing with the Tuner's clamped 1/32-of-space budget.
+    On a TPU backend the default evaluator times the compiled kernel on
+    the chip; ``interpret`` (None = by backend) may not be True there.
     With ``record=True`` the winner lands in the tuned-config cache under
     the kernel's ``shape_key`` — together with the structured ``shape``
     dict that makes it transferable — where
@@ -159,7 +161,7 @@ def tune_kernel_distributed(kernel: "TunableKernel | str", shape: Shape, *,
                             budget: Optional[int] = None,
                             engine: "EngineConfig | Dict[str, Any] | None"
                             = None,
-                            interpret: bool = True,
+                            interpret: Optional[bool] = None,
                             extended_space: Optional[bool] = None,
                             warm_start: "bool | int" = True,
                             seed: int = 0,
@@ -219,7 +221,7 @@ class TuningSession:
                  strategy: Optional[str] = None,
                  budget: Optional[int] = None,
                  seed: int = 0,
-                 interpret: bool = True,
+                 interpret: Optional[bool] = None,
                  extended_space: Optional[bool] = None,
                  registry: KernelRegistry = REGISTRY,
                  evaluator_factory=None,

@@ -451,6 +451,24 @@ def test_parse_bool_rejects_truthy_coercion():
             parse_bool(bad)
 
 
+def test_compile_cache_placement(monkeypatch):
+    """JAX's own JAX_COMPILATION_CACHE_DIR wins; otherwise a fixed path in
+    the checkout."""
+    from repro.core.envknobs import configure_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = configure_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_env_bool(monkeypatch):
     monkeypatch.delenv("REPRO_X", raising=False)
     assert env_bool("REPRO_X", True) is True

@@ -4,10 +4,16 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+import pytest
 
+import repro.core.evaluators as evaluators_mod
+import repro.core.tuner as tuner_mod
 from repro.core import (CostModelEvaluator, TPUAnalyticalEvaluator, Tuner,
                         TuningCache, WallClockEvaluator, TPU_V5E, TPU_V3)
-from repro.core.evaluators import KernelSpec
+from repro.core.evaluators import KernelSpec, resolve_interpret
+from repro.core.profiles import attached_profile, profile_for_kind
+from repro.core.verify import VerificationError, assert_trees_close
 
 N = 1024
 
@@ -124,3 +130,60 @@ def test_cache_only_if_better(tmp_path):
     assert not c.record("k", "s", "p", {"a": 2}, 3.0, "full", 1)
     assert c.record("k", "s", "p", {"a": 3}, 1.0, "full", 1)
     assert c.get("k", "s", "p").config == {"a": 3}
+
+
+# -- TPU backend defaults (backend faked: these run on the host) -----------
+
+def _fake_tpu(monkeypatch, kind="TPU v5 lite"):
+    monkeypatch.setattr(evaluators_mod, "on_tpu", lambda: True)
+    monkeypatch.setattr(tuner_mod, "on_tpu", lambda: True)
+    monkeypatch.setattr(tuner_mod, "attached_profile",
+                        lambda: profile_for_kind(kind))
+
+
+def test_interpret_resolves_by_backend(monkeypatch):
+    assert resolve_interpret(None) is True          # host: the interpreter
+    assert resolve_interpret(False) is False
+    _fake_tpu(monkeypatch)
+    assert resolve_interpret(None) is False         # TPU: compiled
+    with pytest.raises(ValueError, match="TPU backend"):
+        resolve_interpret(True)
+
+
+def test_default_evaluator_times_the_device_on_tpu(monkeypatch):
+    _fake_tpu(monkeypatch)
+    t = Tuner.from_tunable("gemm", {"M": 256, "N": 256, "K": 256})
+    assert isinstance(t.evaluator, WallClockEvaluator)
+    # a profile that is not the attached chip's would mislabel timings
+    with pytest.raises(ValueError, match="attached chip"):
+        Tuner.from_tunable("gemm", {"M": 256, "N": 256, "K": 256},
+                           profile=TPU_V3)
+    # the model stays available when asked for explicitly
+    t = Tuner.from_tunable("gemm", {"M": 256, "N": 256, "K": 256},
+                           evaluator=TPUAnalyticalEvaluator())
+    assert isinstance(t.evaluator, TPUAnalyticalEvaluator)
+
+
+def test_host_default_evaluator_is_the_model():
+    t = Tuner.from_tunable("gemm", {"M": 256, "N": 256, "K": 256})
+    assert isinstance(t.evaluator, TPUAnalyticalEvaluator)
+
+
+def test_device_kind_lookup():
+    assert profile_for_kind("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="TPU v9"):
+        profile_for_kind("TPU v9")
+    with pytest.raises(RuntimeError, match="'cpu'"):
+        attached_profile()                          # tests run on the host
+
+
+def test_verification_is_normwise():
+    """A long reduction's near-zero outputs carry rounding error on the
+    scale of its terms: that passes, an error on that scale fails."""
+    ref = np.array([100.0, 1e-3, -50.0], np.float32)
+    assert_trees_close(ref + np.float32(5e-4), ref)  # 5e-6 of max|ref|
+    with pytest.raises(VerificationError, match="max_abs_err"):
+        assert_trees_close(ref + np.float32(5e-2), ref)
+    with pytest.raises(VerificationError):
+        assert_trees_close(np.array([np.nan, 1.0], np.float32),
+                           np.array([0.0, 1.0], np.float32))

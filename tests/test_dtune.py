@@ -178,6 +178,22 @@ def test_run_workers_rejects_unknown_driver():
         run_workers([], driver="carrier-pigeon")
 
 
+def test_process_driver_refuses_device_timing_on_tpu(tmp_path, monkeypatch):
+    """One process holds a TPU: forked workers could not time on it."""
+    import repro.dtune.worker as worker_mod
+    monkeypatch.setattr(worker_mod, "on_tpu", lambda: True)
+    shard = Shard(index=0, total=1, mode="strided", strategy="full",
+                  strategy_kwargs={"offset": 0, "stride": 1})
+    for evaluator in (None, "wallclock", {"name": "wallclock"}):
+        with pytest.raises(ValueError, match="one process"):
+            run_workers([_spec(tmp_path, shard, evaluator=evaluator)],
+                        driver="process")
+    # a model-timed fleet touches no device and may still fork
+    res = run_workers([_spec(tmp_path, shard)], driver="process",
+                      timeout_s=120)
+    assert res[0].status == "ok"
+
+
 def test_evaluator_spec_forms(tmp_path):
     from repro.dtune.worker import resolve_evaluator
     from repro.core import TPUAnalyticalEvaluator

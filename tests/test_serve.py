@@ -70,3 +70,21 @@ def test_engine_max_steps_returns_unfinished_flagged(setup, caplog):
     done = engine.run()
     assert {r.rid for r in done} == {0, 1}
     assert all(r.done and len(r.output) == 6 for r in done)
+
+
+def test_engine_raises_before_decode_position_passes_max_len(setup):
+    """Slots share one decode position; a batch that would write past the
+    last KV row raises instead of clamping the write onto that row."""
+    cfg, params = setup
+    engine = ServeEngine(cfg, params, slots=1, max_len=8)
+    # 4 prompt + 5 new tokens = 8 steps: positions 0..7 fit exactly
+    engine.submit(Request(rid=0, prompt=[1, 2, 3, 4], max_new_tokens=5))
+    (done,) = engine.run()
+    assert done.done and len(done.output) == 5
+    # the slot is free again, so the next batch restarts at row 0
+    engine.submit(Request(rid=1, prompt=[1, 2, 3, 4], max_new_tokens=5))
+    assert engine.run()[0].done
+    # 4 + 6 = 9 steps cannot fit 8 rows
+    engine.submit(Request(rid=2, prompt=[1, 2, 3, 4], max_new_tokens=6))
+    with pytest.raises(RuntimeError, match="max_len=8"):
+        engine.run()

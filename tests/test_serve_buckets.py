@@ -96,7 +96,9 @@ def test_trace_evaluator_factory_requires_analytical_model():
 
 def test_bucket_assignment_and_completion(model_setup, cache):
     cfg, params = model_setup
-    engine = BucketedServeEngine(cfg, params, buckets=(16, 64), slots=2,
+    # 3 slots: the 16-bucket's three requests decode as one batch; with 2,
+    # the refill would run that batch past the bucket's 16 KV rows
+    engine = BucketedServeEngine(cfg, params, buckets=(16, 64), slots=3,
                                  cache=cache, online_tune=False)
     try:
         reqs = _ragged_requests(cfg)
