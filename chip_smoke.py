@@ -1,6 +1,6 @@
 """Smoke run of the tuner's main path on one TPU chip.
 
-    python chip_smoke.py [--out DIR] [--seed N]
+    python chip_smoke.py [--out DIR]
 
 One process, phases in order; any failed phase exits non-zero:
 
@@ -33,6 +33,7 @@ sys.path.insert(0, str(ROOT / "src"))
 
 KERNELS = ("gemm", "flash_attention", "conv2d")
 TUNE_BUDGET = 8
+SEED = 0                 # weights, prompts, kernel inputs and the search
 SERVE_ARCH = "granite-3-2b"
 SLOTS, MAX_LEN, PROMPT_LEN, NEW_TOKENS = 4, 256, 8, 16
 
@@ -58,7 +59,7 @@ def device_phase():
     return dev, profile
 
 
-def kernel_phase(name, profile, cache, seed):
+def kernel_phase(name, profile, cache):
     import jax
     import numpy as np
 
@@ -70,7 +71,7 @@ def kernel_phase(name, profile, cache, seed):
     shape = dict(k.default_shapes[0])
     cfg = k.heuristic(shape)
     fn = jax.jit(k.builder(shape, cfg, interpret=False))
-    args = k.make_args(shape, np.random.default_rng(seed))
+    args = k.make_args(shape, np.random.default_rng(SEED))
     t0 = time.perf_counter()
     hlo = fn.lower(*args).compile().as_text()
     compile_s = time.perf_counter() - t0
@@ -89,7 +90,7 @@ def kernel_phase(name, profile, cache, seed):
 
     t0 = time.perf_counter()
     outcome = tune_kernel(k, shape, budget=TUNE_BUDGET, profile=profile,
-                          cache=cache, interpret=False, seed=seed,
+                          cache=cache, interpret=False, seed=SEED,
                           extended_space=False, record=True)
     wall_s = time.perf_counter() - t0
     trials = outcome.result.trials
@@ -107,7 +108,7 @@ def kernel_phase(name, profile, cache, seed):
           f"{name}: winner not recorded in {cache.path}")
 
 
-def serve_phase(profile, cache, seed):
+def serve_phase(profile, cache):
     import jax
     import numpy as np
 
@@ -117,13 +118,13 @@ def serve_phase(profile, cache, seed):
 
     cfg = get_config(SERVE_ARCH, smoke=False)
     t0 = time.perf_counter()
-    params = jax.block_until_ready(init_model(cfg, jax.random.PRNGKey(seed)))
+    params = jax.block_until_ready(init_model(cfg, jax.random.PRNGKey(SEED)))
     print(f"serve: {cfg.name} {cfg.num_layers} layers d_model={cfg.d_model} "
           f"vocab={cfg.vocab_size}, random weights in "
           f"{time.perf_counter() - t0:.1f} s", flush=True)
     engine = ServeEngine(cfg, params, slots=SLOTS, max_len=MAX_LEN,
                          profile=profile, cache=cache, online_tune=False)
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     for rid in range(SLOTS):
         prompt = rng.integers(0, cfg.vocab_size, PROMPT_LEN).tolist()
         engine.submit(Request(rid=rid, prompt=prompt,
@@ -156,7 +157,6 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--out", default=str(ROOT / "experiments" / "chip_smoke"),
                     help="directory for the run's tuned-config cache")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
     from repro.core.envknobs import configure_compile_cache
@@ -174,8 +174,8 @@ def main() -> int:
     cache_path.unlink(missing_ok=True)         # fresh: this run's winners only
     cache = TuningCache(str(cache_path))
     for name in KERNELS:
-        kernel_phase(name, profile, cache, args.seed)
-    serve_phase(profile, cache, args.seed)
+        kernel_phase(name, profile, cache)
+    serve_phase(profile, cache)
 
     import jax
 

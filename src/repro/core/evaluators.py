@@ -21,9 +21,11 @@ Three evaluators, one interface:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import math
+import threading
 import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -233,6 +235,20 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+#: one per process: the chip runs one program at a time, so a host-clock
+#: sample taken while another thread's kernel or decode step runs on it
+#: would count that work too
+_DEVICE_LOCK = threading.RLock()
+
+
+def device_lock():
+    """Held around work run on a TPU whose time is read or contended:
+    wall-clock trials (every engine, every dtune thread worker) and serve
+    decode steps.  A no-op on a host backend, where timings stay as
+    concurrent as the caller makes them."""
+    return _DEVICE_LOCK if on_tpu() else contextlib.nullcontext()
+
+
 def resolve_interpret(interpret: Optional[bool]) -> bool:
     """Whether Pallas kernels build in interpret mode.
 
@@ -257,7 +273,7 @@ def _failed(err: Exception | str, compile_s: float = 0.0) -> Measurement:
 
 @dataclasses.dataclass
 class _CompiledKernel:
-    """Artifact of WallClockEvaluator.prepare: jitted fn, args, first output."""
+    """Artifact of WallClockEvaluator.prepare: compiled fn, args, first output."""
 
     fn: Callable
     args: Tuple
@@ -268,15 +284,18 @@ class _CompiledKernel:
 class WallClockEvaluator(Evaluator):
     """Median-of-N wall-clock timing of the jitted kernel (CLTune's method).
 
-    ``prepare`` performs the expensive part — building and jit-compiling
-    the kernel plus the first (compiling) call — and returns a
+    ``prepare`` performs the expensive part — building and compiling
+    the kernel plus its first call — and returns a
     :class:`CompiledArtifact` whose payload is the live ``_CompiledKernel``
-    bundle (jitted fn, concrete args, first output).  A live executable
+    bundle (compiled fn, concrete args, first output).  A live executable
     does not serialize, so the artifact is *not persistable*: wall-clock
     artifacts never reach the on-disk store and their fingerprint is the
-    spec/config content address (no lowering happens separately from
-    jit).  ``measure`` verifies and times serially, optionally aborting
+    spec/config content address.  ``measure`` verifies and times serially, optionally aborting
     early once the running median exceeds the prune threshold.
+
+    On a TPU every device run here holds :func:`device_lock`, so trials
+    from concurrent engines (dtune thread workers, a background retune
+    beside a serving loop) are timed one at a time.
     """
 
     name = "wallclock"
@@ -296,10 +315,12 @@ class WallClockEvaluator(Evaluator):
         rng = np.random.default_rng(self.seed)
         try:
             args = spec.make_args(rng)
-            fn = jax.jit(spec.build(config))
             t0 = time.perf_counter()
-            out = fn(*args)
-            jax.block_until_ready(out)
+            # compile outside the device lock (compiles overlap), run the
+            # first call under it
+            fn = jax.jit(spec.build(config)).lower(*args).compile()
+            with device_lock():
+                out = jax.block_until_ready(fn(*args))
             compile_s = time.perf_counter() - t0
         except Exception as e:  # noqa: BLE001 — any build/compile error = failed config
             raise CompileError(f"{type(e).__name__}: {e}") from e
@@ -320,6 +341,11 @@ class WallClockEvaluator(Evaluator):
             return prepared
         if isinstance(prepared, CompiledArtifact):
             prepared = prepared.payload         # legacy _CompiledKernel passes as-is
+        with device_lock():
+            return self._measure_locked(spec, prepared, prune_threshold_s)
+
+    def _measure_locked(self, spec: KernelSpec, prepared: _CompiledKernel,
+                        prune_threshold_s: Optional[float]) -> Measurement:
         fn, args, out = prepared.fn, prepared.args, prepared.out
         compile_s = prepared.compile_s
 

@@ -153,6 +153,22 @@ def attached_profile() -> DeviceProfile:
     return profile_for_kind(dev.device_kind)
 
 
+def kernel_vmem_limit() -> int:
+    """``vmem_limit_bytes`` for every Pallas kernel's compile.
+
+    On a TPU backend: the attached chip's VMEM budget, the one
+    ``repro.analyze`` proves footprints against for that chip's profile
+    (an unknown ``device_kind`` raises, as in :func:`attached_profile`).
+    On a host backend a compiled (non-interpret) build can only be an
+    ahead-of-time compile for a described topology; that target is
+    ``TPU_V5E``, the one chip in :data:`DEVICE_KINDS`."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        return attached_profile().vmem_bytes
+    return TPU_V5E.vmem_bytes
+
+
 def get_profile(name: str) -> DeviceProfile:
     try:
         return PROFILES[name]

@@ -17,8 +17,13 @@ cache file and the coordinator folds those into the shared cache with
 Two drivers run a fleet of specs:
 
 * ``thread`` — in-process pool.  Zero setup cost; right for analytical /
-  cost-model evaluators (pure Python, cheap) and for tests, and the only
-  driver that can time kernels on a TPU, which one process holds.
+  cost-model evaluators (pure Python, cheap) and for tests.  Wall-clock
+  measurement in concurrent threads contends for the device: on a TPU
+  every trial holds the process-wide ``device_lock``, so the workers'
+  compiles overlap but their timings run one at a time; on a host
+  backend they run concurrently and each median counts the others'
+  work.  It is the only driver that can time kernels on a TPU, which
+  one process holds.
 * ``process`` — one OS process per worker (``fork`` server where
   available, ``spawn`` otherwise).  True isolation: a worker segfaulting
   in a compiler cannot take the coordinator down; results come back over

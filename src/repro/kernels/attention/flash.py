@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.profiles import TPU_V5E, DeviceProfile
+from ...core.profiles import DeviceProfile, kernel_vmem_limit
 
 Config = Dict[str, Any]
 
@@ -99,10 +99,10 @@ def make_flash_attention(Sq: int, Sk: int, D: int,
         sq=Sq, sk=Sk, bq=bq, bk=bk)
     kwargs: Dict[str, Any] = {}
     if not interpret:
-        # the VMEM budget the static proofs check footprints against
+        # the chip's VMEM budget, the one the static proofs check against
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=TPU_V5E.vmem_bytes)
+            vmem_limit_bytes=kernel_vmem_limit())
     return pl.pallas_call(
         kernel,
         grid=(Sq // bq, nk),

@@ -36,7 +36,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.profiles import TPU_V5E, DeviceProfile
+from ...core.profiles import DeviceProfile, kernel_vmem_limit
 from .ref import conv2d_reference
 
 Config = Dict[str, Any]
@@ -145,10 +145,10 @@ def make_conv2d(H: int, W: int, Fh: int, Fw: int,
         tiles, gh, gw = _materialise_tiles(image, bh, bw, hh, hw)
         kwargs: Dict[str, Any] = {}
         if not interpret:
-            # the VMEM budget the static proofs check footprints against
+            # the chip's VMEM budget, the one the static proofs check against
             kwargs["compiler_params"] = pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
-                vmem_limit_bytes=TPU_V5E.vmem_bytes)
+                vmem_limit_bytes=kernel_vmem_limit())
         out = pl.pallas_call(
             kernel,
             grid=(gh, gw),

@@ -33,7 +33,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.profiles import TPU_V5E, DeviceProfile
+from ...core.profiles import DeviceProfile, kernel_vmem_limit
 
 Config = Dict[str, Any]
 
@@ -173,12 +173,12 @@ def make_matmul(M: int, N: int, K: int, config: Config | None = None,
         out_shape=out_shape, interpret=interpret)
     if not interpret:
         # M/N grid dims are embarrassingly parallel; K carries the
-        # accumulator dependency.  The VMEM limit is the budget the
-        # static proofs check footprints against (the compiler's own
+        # accumulator dependency.  The VMEM limit is the chip's budget,
+        # the one the static proofs check footprints against (the compiler's own
         # default scoped limit is far smaller).
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=TPU_V5E.vmem_bytes)
+            vmem_limit_bytes=kernel_vmem_limit())
 
     if cfg["ACC_IN_OUTPUT"]:
         kernel = functools.partial(_mm_kernel_inplace, **common)

@@ -28,7 +28,7 @@ from .. import kernels  # noqa: F401 — populates the tunable registry
 from ..core.cache import (CacheEntry, OBJ_PREFIX, TuningCache, default_cache,
                           normalize_objective, split_key)
 from ..core.envknobs import env_bool, env_str
-from ..core.evaluators import ArrivalTraceEvaluator, on_tpu
+from ..core.evaluators import ArrivalTraceEvaluator, device_lock, on_tpu
 from ..core.profiles import DeviceProfile, TPU_V5E
 from ..core.registry import (AutotunePolicy, REGISTRY, Resolution,
                              lookup_resolved)
@@ -426,12 +426,14 @@ class ServeEngine:
                     f"one continuous batch (refills included) may run at "
                     f"most max_len steps")
             tokens = self._current_tokens()
-            next_tok, self.cache = self._step(self.params, self.cache,
-                                              tokens, self._pos)
+            with device_lock():     # a background retune times on the chip
+                next_tok, self.cache = self._step(self.params, self.cache,
+                                                  tokens, self._pos)
+                next_tok = np.asarray(next_tok)
             self._pos += 1
             steps += 1
             self._steps_total += 1
-            self._absorb(np.asarray(next_tok), finished)
+            self._absorb(next_tok, finished)
         unfinished = ([r for r in self._slot_req if r is not None]
                       + list(self._queue))
         if unfinished:

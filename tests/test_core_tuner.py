@@ -187,3 +187,54 @@ def test_verification_is_normwise():
     with pytest.raises(VerificationError):
         assert_trees_close(np.array([np.nan, 1.0], np.float32),
                            np.array([0.0, 1.0], np.float32))
+
+
+@pytest.mark.parametrize("tpu", [False, True])
+def test_wallclock_trials_time_one_at_a_time_on_tpu(monkeypatch, tpu):
+    """Concurrent engines (dtune thread workers, a retune beside serving)
+    share one chip: on a TPU their timing loops run one at a time."""
+    import threading
+    import time
+
+    from repro.core.evaluators import _CompiledKernel
+    monkeypatch.setattr(evaluators_mod, "on_tpu", lambda: tpu)
+    lock = threading.Lock()
+    active, peak = [0], [0]
+
+    def kernel():
+        with lock:
+            active[0] += 1
+            peak[0] = max(peak[0], active[0])
+        time.sleep(0.02)
+        with lock:
+            active[0] -= 1
+        return np.zeros(1)
+
+    ev = WallClockEvaluator(repeats=3, warmup=1)
+    spec = KernelSpec(name="sleep", build=lambda cfg: kernel)
+    barrier = threading.Barrier(2)
+
+    def trial():
+        barrier.wait()
+        ev.measure(spec, {}, _CompiledKernel(fn=kernel, args=(), out=None,
+                                             compile_s=0.0))
+    threads = [threading.Thread(target=trial) for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert peak[0] == (1 if tpu else 2)
+
+
+def test_kernel_vmem_limit_follows_the_attached_chip(monkeypatch):
+    import repro.core.profiles as profiles_mod
+    from repro.core.profiles import kernel_vmem_limit
+    # host backend: ahead-of-time compiles target the v5e
+    assert kernel_vmem_limit() == TPU_V5E.vmem_bytes
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(profiles_mod, "attached_profile", lambda: TPU_V3)
+    assert kernel_vmem_limit() == TPU_V3.vmem_bytes
+    monkeypatch.setattr(profiles_mod, "attached_profile",
+                        lambda: profile_for_kind("TPU v9"))
+    with pytest.raises(KeyError, match="TPU v9"):
+        kernel_vmem_limit()

@@ -1,5 +1,7 @@
 """Serving engine: continuous batching, greedy decode consistency."""
 
+import threading
+
 import jax
 import numpy as np
 import pytest
@@ -88,3 +90,37 @@ def test_engine_raises_before_decode_position_passes_max_len(setup):
     engine.submit(Request(rid=2, prompt=[1, 2, 3, 4], max_new_tokens=6))
     with pytest.raises(RuntimeError, match="max_len=8"):
         engine.run()
+
+
+def _held_by_another_thread(lock) -> bool:
+    got = []
+
+    def probe():
+        got.append(lock.acquire(blocking=False))
+        if got[0]:
+            lock.release()
+    t = threading.Thread(target=probe)
+    t.start()
+    t.join()
+    return not got[0]
+
+
+@pytest.mark.parametrize("tpu", [False, True])
+def test_decode_steps_hold_the_device_lock_on_tpu(setup, monkeypatch, tpu):
+    """On a TPU a background retune times kernels on the chip; decode
+    steps take the same lock, so neither's timing counts the other."""
+    import repro.core.evaluators as evaluators_mod
+    monkeypatch.setattr(evaluators_mod, "on_tpu", lambda: tpu)
+    cfg, params = setup
+    engine = ServeEngine(cfg, params, slots=1, max_len=16)
+    held = []
+    step = engine._step
+
+    def probed_step(*args):
+        held.append(_held_by_another_thread(evaluators_mod._DEVICE_LOCK))
+        return step(*args)
+    engine._step = probed_step
+    engine.submit(Request(rid=0, prompt=[1, 2], max_new_tokens=2))
+    (done,) = engine.run()
+    assert done.done and len(held) == 3
+    assert held == [tpu] * 3
