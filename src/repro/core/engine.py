@@ -56,6 +56,9 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
+
+from . import spans
 from .artifacts import CompiledArtifact
 from .evaluators import Evaluator, KernelSpec, Measurement
 from .failures import (CircuitBreakerTripped, CompileError, FailureRecord,
@@ -172,6 +175,13 @@ class EngineConfig:
                             "(config -> list of violations) or None")
 
 
+#: the evaluator phases ``EngineStats`` sums: the names an evaluator gives
+#: their seconds in ``CompiledArtifact.stats`` (prepare's) and
+#: ``Measurement.detail`` (measure's)
+PHASES = ("args_s", "lower_s", "xla_compile_s", "first_call_s", "verify_s",
+          "timing_s")
+
+
 @dataclasses.dataclass
 class EngineStats:
     """Observability record for one engine run (serialized into results)."""
@@ -196,12 +206,20 @@ class EngineStats:
     retries: int = 0                # extra evaluation attempts made
     aborted: bool = False           # circuit-breaker stopped the search
     batches: int = 0
-    max_batch: int = 0
     compile_total_s: float = 0.0    # sum of per-config compile durations
     compile_wait_s: float = 0.0     # wall time the serial loop blocked on
                                     # compile futures
     measure_total_s: float = 0.0
     wall_s: float = 0.0
+    # the evaluator's phases (PHASES), summed over the configs evaluated;
+    # 0 for evaluators that report none.  A compile that raises loses its
+    # phases with the artifact, a measure that raises loses its own.
+    args_s: float = 0.0             # building the trial's inputs
+    lower_s: float = 0.0            # tracing and lowering the kernel
+    xla_compile_s: float = 0.0      # the compiler
+    first_call_s: float = 0.0       # the first call, under the device lock
+    verify_s: float = 0.0           # reference, host copy and comparison
+    timing_s: float = 0.0           # warm-up and timed samples
 
     @property
     def compile_overlap_ratio(self) -> float:
@@ -222,12 +240,18 @@ class EngineStats:
         per-run memo or by the persistent artifact store."""
         return self.memo_hits + self.artifact_hits
 
+    def add_phases(self, seconds: Dict[str, float]) -> None:
+        """Add an evaluator's phase seconds (``CompiledArtifact.stats`` or
+        ``Measurement.detail``) into the matching fields."""
+        for name in PHASES:
+            setattr(self, name, getattr(self, name) + seconds.get(name, 0.0))
+
     def as_dict(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
         d["compiles_avoided"] = self.compiles_avoided
         d["compile_overlap_ratio"] = round(self.compile_overlap_ratio, 4)
         for k in ("compile_total_s", "compile_wait_s", "measure_total_s",
-                  "wall_s"):
+                  "wall_s") + PHASES:
             d[k] = round(d[k], 6)
         return d
 
@@ -267,6 +291,13 @@ class EvaluationEngine:
         prepared = self.evaluator.prepare(self.spec, config)
         return prepared, time.perf_counter() - t0
 
+    def _compile_wait(self, config: Config):
+        """Span the serial loop's wait on ``config``'s compile, adding its
+        seconds into ``EngineStats.compile_wait_s`` (the stats' fields are
+        its instance dict)."""
+        return spans.phase("repro.engine.compile_wait", vars(self.stats),
+                           "compile_wait_s", config=spans.config_arg(config))
+
     def _submit(self, pool: Optional[ThreadPoolExecutor],
                 config: Config) -> "Future":
         self.stats.compile_calls += 1
@@ -274,8 +305,8 @@ class EvaluationEngine:
             # inline compile blocks the serial loop: all of it is wait time
             fut: Future = Future()
             try:
-                result = self._timed_prepare(config)
-                self.stats.compile_wait_s += result[1]
+                with self._compile_wait(config):
+                    result = self._timed_prepare(config)
                 fut.set_result(result)
             except BaseException as e:  # noqa: BLE001
                 fut.set_exception(e)
@@ -325,12 +356,10 @@ class EvaluationEngine:
             try:
                 if not have_artifact:
                     if fut is not None:
-                        t_wait0 = time.perf_counter()
                         try:
-                            prepared, compile_s = fut.result()
+                            with self._compile_wait(config):
+                                prepared, compile_s = fut.result()
                         finally:
-                            self.stats.compile_wait_s += (time.perf_counter()
-                                                          - t_wait0)
                             fut = None  # a retry must recompile, not re-read
                     else:   # retry: the pooled compile already failed us
                         self.stats.compile_calls += 1
@@ -341,9 +370,10 @@ class EvaluationEngine:
                         # returning a failed Measurement instead of raising
                         raise CompileError(prepared.error
                                            or "prepare() reported failure")
-                    if (isinstance(prepared, CompiledArtifact)
-                            and prepared.from_store):
-                        self.stats.artifact_hits += 1
+                    if isinstance(prepared, CompiledArtifact):
+                        self.stats.add_phases(prepared.stats)
+                        if prepared.from_store:
+                            self.stats.artifact_hits += 1
                     have_artifact = True
                 stage = "measure"
                 threshold = None
@@ -364,6 +394,7 @@ class EvaluationEngine:
                 finally:
                     self.stats.measure_total_s += (time.perf_counter()
                                                    - t_meas0)
+                self.stats.add_phases(m.detail)
                 if not m.ok:
                     # legacy not-ok Measurement: a failure trial, not a
                     # crash.  Coerce the objective to inf — a not-ok
@@ -591,11 +622,11 @@ class EvaluationEngine:
                                "failures": len(self.failures),
                                "stopped": True}
                     break
-                batch = driver.ask()
+                with jax.profiler.TraceAnnotation("repro.engine.strategy"):
+                    batch = driver.ask()
                 if not batch:
                     break
                 self.stats.batches += 1
-                self.stats.max_batch = max(self.stats.max_batch, len(batch))
                 # 0. proven-infeasible first (static resource proof, no
                 #    hedge), then predictor ranking/pruning on the rest
                 batch, proven_pruned = self._proven_gate(batch)
@@ -645,7 +676,8 @@ class EvaluationEngine:
                 # a partial tell (breaker mid-batch) is fine: every driver
                 # accepts fewer results than it asked for
                 if results:
-                    driver.tell(results)
+                    with jax.profiler.TraceAnnotation("repro.engine.strategy"):
+                        driver.tell(results)
             if aborted is None:
                 result = driver.result()
             else:

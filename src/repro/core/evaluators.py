@@ -33,7 +33,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from . import verify
+from . import spans, verify
 from .artifacts import (PROVENANCE_NONE, ArtifactStore, CompiledArtifact,
                         spec_fingerprint)
 from .failures import (CompileError, EvaluationError, InfeasibleConfigError,
@@ -293,6 +293,11 @@ class WallClockEvaluator(Evaluator):
     spec/config content address.  ``measure`` verifies and times serially, optionally aborting
     early once the running median exceeds the prune threshold.
 
+    Each phase is a span of :mod:`repro.core.spans` whose seconds are
+    returned beside the result: ``args_s``, ``lower_s``, ``xla_compile_s``
+    and ``first_call_s`` in the artifact's ``stats``, ``verify_s`` and
+    ``timing_s`` in the measurement's ``detail``.
+
     On a TPU every device run here holds :func:`device_lock`, so trials
     from concurrent engines (dtune thread workers, a background retune
     beside a serving loop) are timed one at a time.
@@ -313,23 +318,35 @@ class WallClockEvaluator(Evaluator):
         if spec.make_args is None:
             raise CompileError("WallClockEvaluator requires spec.make_args")
         rng = np.random.default_rng(self.seed)
+        trial = spans.config_arg(config)
+        seconds: Dict[str, float] = {}
         try:
-            args = spec.make_args(rng)
-            t0 = time.perf_counter()
+            with spans.phase("repro.eval.args", seconds, "args_s",
+                             config=trial):
+                args = spec.make_args(rng)
             # compile outside the device lock (compiles overlap), run the
             # first call under it
-            fn = jax.jit(spec.build(config)).lower(*args).compile()
-            with device_lock():
-                out = jax.block_until_ready(fn(*args))
-            compile_s = time.perf_counter() - t0
+            with spans.phase("repro.eval.lower", seconds, "lower_s",
+                             config=trial):
+                lowered = jax.jit(spec.build(config)).lower(*args)
+            with spans.phase("repro.eval.compile", seconds, "xla_compile_s",
+                             config=trial):
+                fn = lowered.compile()
+            with spans.phase("repro.eval.first_call", seconds,
+                             "first_call_s", config=trial):
+                with device_lock():
+                    out = jax.block_until_ready(fn(*args))
         except Exception as e:  # noqa: BLE001 — any build/compile error = failed config
             raise CompileError(f"{type(e).__name__}: {e}") from e
+        compile_s = (seconds["lower_s"] + seconds["xla_compile_s"]
+                     + seconds["first_call_s"])
         kernel = _CompiledKernel(fn=fn, args=args, out=out, compile_s=compile_s)
         return CompiledArtifact(
             kind=self.name,
             fingerprint=spec_fingerprint(spec.name, spec.meta, config,
                                          extra=f"seed={self.seed}"),
-            profile="", payload=kernel, stats={"compile_s": compile_s},
+            profile="", payload=kernel,
+            stats=dict(seconds, compile_s=compile_s),
             compile_s=compile_s, persistable=False)
 
     def measure(self, spec: KernelSpec, config: Config,
@@ -342,42 +359,50 @@ class WallClockEvaluator(Evaluator):
         if isinstance(prepared, CompiledArtifact):
             prepared = prepared.payload         # legacy _CompiledKernel passes as-is
         with device_lock():
-            return self._measure_locked(spec, prepared, prune_threshold_s)
+            return self._measure_locked(spec, config, prepared,
+                                        prune_threshold_s)
 
-    def _measure_locked(self, spec: KernelSpec, prepared: _CompiledKernel,
+    def _measure_locked(self, spec: KernelSpec, config: Config,
+                        prepared: _CompiledKernel,
                         prune_threshold_s: Optional[float]) -> Measurement:
         fn, args, out = prepared.fn, prepared.args, prepared.out
         compile_s = prepared.compile_s
+        trial = spans.config_arg(config)
+        seconds: Dict[str, float] = {}
 
         verified: Optional[bool] = None
         if self.verify_outputs and spec.reference is not None:
             try:
-                ref_out = spec.reference(*args)
-                verify.assert_trees_close(out, ref_out,
-                                          atol=self.atol, rtol=self.rtol)
+                with spans.phase("repro.eval.verify", seconds, "verify_s",
+                                 config=trial):
+                    ref_out = spec.reference(*args)
+                    verify.assert_trees_close(out, ref_out,
+                                              atol=self.atol, rtol=self.rtol)
                 verified = True
             except Exception as e:  # verification failure => config is invalid
                 raise VerificationFailure(
                     f"verification failed: {e}") from e
 
         try:
-            for _ in range(max(0, self.warmup - 1)):
-                jax.block_until_ready(fn(*args))
+            with spans.phase("repro.eval.timing", seconds, "timing_s",
+                             config=trial):
+                for _ in range(max(0, self.warmup - 1)):
+                    jax.block_until_ready(fn(*args))
 
-            def _sample() -> float:
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(*args))
-                return time.perf_counter() - t0
+                def _sample() -> float:
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(fn(*args))
+                    return time.perf_counter() - t0
 
-            samples, pruned = median_prune_loop(
-                _sample, self.repeats, prune_threshold_s=prune_threshold_s,
-                min_samples=2)
+                samples, pruned = median_prune_loop(
+                    _sample, self.repeats,
+                    prune_threshold_s=prune_threshold_s, min_samples=2)
             t = float(np.median(samples))
         except Exception as e:  # noqa: BLE001
             raise MeasureError(f"{type(e).__name__}: {e}") from e
         detail = {"min_s": float(np.min(samples)),
                   "max_s": float(np.max(samples)),
-                  "samples": float(len(samples))}
+                  "samples": float(len(samples)), **seconds}
         if pruned:
             detail["pruned"] = True
         return Measurement(time_s=t, ok=True, verified=verified,

@@ -26,6 +26,10 @@ from ...core.profiles import DeviceProfile, kernel_vmem_limit
 
 Config = Dict[str, Any]
 
+#: the kernel's name in the compiled program and the device trace, the same
+#: for every configuration
+KERNEL_NAME = "flash_attention"
+
 DEFAULT_CONFIG: Config = {"BLOCK_Q": 256, "BLOCK_K": 512}
 
 _NEG = -1e30
@@ -105,6 +109,7 @@ def make_flash_attention(Sq: int, Sk: int, D: int,
             vmem_limit_bytes=kernel_vmem_limit())
     return pl.pallas_call(
         kernel,
+        name=KERNEL_NAME,
         grid=(Sq // bq, nk),
         in_specs=[
             pl.BlockSpec((bq, D), lambda qi, ki: (qi, 0)),

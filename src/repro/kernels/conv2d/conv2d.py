@@ -41,6 +41,10 @@ from .ref import conv2d_reference
 
 Config = Dict[str, Any]
 
+#: the kernel's name in the compiled program and the device trace, the same
+#: for every configuration
+KERNEL_NAME = "conv2d"
+
 DEFAULT_CONFIG: Config = {
     "BLOCK_H": 16, "BLOCK_W": 256, "SUB_H": 1, "UNROLL": True,
     "HALO_MODE": "materialize",
@@ -151,6 +155,7 @@ def make_conv2d(H: int, W: int, Fh: int, Fw: int,
                 vmem_limit_bytes=kernel_vmem_limit())
         out = pl.pallas_call(
             kernel,
+            name=KERNEL_NAME,
             grid=(gh, gw),
             in_specs=[
                 pl.BlockSpec((1, 1, th, tw), lambda i, j: (i, j, 0, 0)),

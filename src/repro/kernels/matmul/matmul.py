@@ -37,6 +37,10 @@ from ...core.profiles import DeviceProfile, kernel_vmem_limit
 
 Config = Dict[str, Any]
 
+#: the kernel's name in the compiled program and the device trace, the same
+#: for every configuration
+KERNEL_NAME = "gemm"
+
 DEFAULT_CONFIG: Config = {
     "BLOCK_M": 512, "BLOCK_N": 512, "BLOCK_K": 512,
     "GRID_ORDER": "mn", "INNER_STEPS": 1,
@@ -186,7 +190,7 @@ def make_matmul(M: int, N: int, K: int, config: Config | None = None,
         kernel = functools.partial(_mm_kernel_scratch, **common)
         kwargs["scratch_shapes"] = [pltpu.VMEM((bm, bn), acc_dtype)]
 
-    return pl.pallas_call(kernel, **kwargs)
+    return pl.pallas_call(kernel, name=KERNEL_NAME, **kwargs)
 
 
 # ---------------------------------------------------------------------------

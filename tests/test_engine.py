@@ -9,6 +9,8 @@ from repro.core import (EngineConfig, EvaluationEngine, Evaluator, KernelSpec,
                         Measurement, ParticleSwarm, SearchSpace,
                         SimulatedAnnealing, Strategy, make_strategy,
                         median_prune_loop)
+from repro.core.engine import PHASES
+from repro.core.spans import config_arg
 
 
 def make_space(n_params=4, n_values=4):
@@ -269,3 +271,65 @@ def test_batched_drivers_reject_none_budget():
     for name in ("random", "pso", "evolutionary"):
         with pytest.raises(ValueError):
             make_strategy(name).asktell(make_space(), None)
+
+
+# -- phase spans and counters -------------------------------------------------
+
+def _tiny_wallclock_search(tmp_path):
+    """Three GEMM configurations, compiled and timed in interpret mode."""
+    from repro.core import TuningCache, WallClockEvaluator
+    from repro.tune import tune_kernel
+    return tune_kernel("gemm", {"M": 256, "N": 256, "K": 256,
+                                "dtype": "float32"},
+                       strategy="random", budget=3, record=False,
+                       evaluator=WallClockEvaluator(repeats=2),
+                       cache=TuningCache(str(tmp_path / "c.json")))
+
+
+def test_wallclock_search_fills_phase_counters(tmp_path):
+    s = _tiny_wallclock_search(tmp_path).engine_stats
+    assert s["unique_configs"] == 3
+    assert all(s[name] > 0 for name in PHASES), s
+    # each phase lies inside the engine's interval around its call; the
+    # slack is as_dict's rounding to 6 places
+    prepare = (s["args_s"] + s["lower_s"] + s["xla_compile_s"]
+               + s["first_call_s"])
+    assert 0.9 * s["compile_total_s"] <= prepare <= s["compile_total_s"] + 1e-5
+    measure = s["verify_s"] + s["timing_s"]
+    assert 0.9 * s["measure_total_s"] <= measure <= s["measure_total_s"] + 1e-5
+
+
+def test_wallclock_search_spans_reach_the_profiler_trace(tmp_path):
+    import collections
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path / "trace"),
+                             profiler_options=options)
+    try:
+        out = _tiny_wallclock_search(tmp_path)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                       recursive=True)
+    spans = [(e.name, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.")]
+    counts = collections.Counter(name for name, _ in spans)
+    for phase in ("args", "lower", "compile", "first_call", "verify",
+                  "timing"):
+        assert counts[f"repro.eval.{phase}"] == 3, counts
+    assert counts["repro.engine.compile_wait"] == 3
+    assert counts["repro.engine.strategy"] >= 2      # an ask and a tell
+    # one trial's compile and measure spans join on its configuration
+    trials = {config_arg(t.config) for t in out.result.trials}
+    for phase in ("repro.eval.compile", "repro.eval.timing",
+                  "repro.engine.compile_wait"):
+        assert {a["config"] for name, a in spans if name == phase} == trials

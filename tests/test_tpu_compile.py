@@ -57,6 +57,19 @@ def _compile(kernel, shape, config, sharding):
     return fn.lower(*specs).compile()
 
 
+#: each kernel's Pallas name, which the compiled program and the device
+#: trace carry whatever the configuration
+KERNEL_NAMES = {"gemm": "gemm", "flash_attention": "flash_attention",
+                "conv2d": "conv2d"}
+
+
+def _assert_named_kernel(kernel, compiled):
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls, "no Pallas kernel in the compiled program"
+    assert any(f"%{KERNEL_NAMES[kernel]}." in line for line in calls), calls
+
+
 def _heuristic(kernel, shape):
     return resolve(kernel).heuristic(dict(shape))
 
@@ -81,7 +94,7 @@ def _conv(unroll):
 def test_kernel_compiles_for_v5e(one_chip, kernel, shape, config):
     config = config or _heuristic(kernel, shape)
     compiled = _compile(kernel, shape, config, one_chip)
-    assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_kernel(kernel, compiled)
 
 
 @pytest.mark.parametrize("kernel,shape,config", [
@@ -97,7 +110,7 @@ def test_vmem_budget_agrees_with_analyzer(one_chip, kernel, shape, config):
     config = dict(_heuristic(kernel, shape), **config)
     assert TPU_V5E.fits_vmem(k.vmem_footprint(shape, config))
     compiled = _compile(kernel, shape, config, one_chip)
-    assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_kernel(kernel, compiled)
 
 
 @pytest.mark.parametrize("override,match", [
