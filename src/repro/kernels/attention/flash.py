@@ -9,6 +9,14 @@ Tunables:
 
 The kernel keeps a running max m, normaliser l and accumulator acc in VMEM
 scratch across KV blocks (grid dim 1, 'arbitrary'); Q blocks are parallel.
+
+Causal calls skip the KV blocks that no query of the current query block
+can see (``last_visible_block``): such a grid step neither computes nor
+fetches, since the K/V index maps name the last visible block again and the
+pipeline issues no copy for a block it already holds.  Where Sk >= Sq the
+outputs equal, bit for bit, those of a kernel that computes every block: a
+fully masked block adds exactly zero to a row that has seen a key, and the
+visible blocks keep their order.
 """
 
 from __future__ import annotations
@@ -39,9 +47,39 @@ _NEG = -1e30
 _PRECISION = jax.lax.Precision.HIGHEST
 
 
+def last_visible_block(qi, *, sq: int, sk: int, bq: int, bk: int):
+    """Index of the last KV block that some query of query block ``qi`` can
+    see under the causal mask, whose query block ends align with the KV end.
+
+    ``qi`` is a traced grid index or an array of them; a block of queries
+    that see no key at all (only where Sq > Sk) gets block 0.
+    """
+    # lax.div truncates where // floors; the two differ only below zero,
+    # which the clip maps to 0 either way, and truncation lowers to one
+    # scalar op where floor division adds sign corrections
+    last = jax.lax.div(qi * bq + bq - 1 + (sk - sq), bk)
+    return jnp.clip(last, 0, sk // bk - 1)
+
+
+def causal_block_counts(Sq: int, Sk: int, bq: int, bk: int,
+                        causal: bool) -> tuple[int, int]:
+    """(KV blocks the kernel computes, grid steps) for one head."""
+    nq, nk = Sq // bq, Sk // bk
+    total = nq * nk
+    if not causal:
+        return total, total
+    last = last_visible_block(jnp.arange(nq), sq=Sq, sk=Sk, bq=bq, bk=bk)
+    return int(jnp.sum(last + 1)), total
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   nk: int, scale: float, causal: bool, sq: int, sk: int,
                   bq: int, bk: int):
+    """One (query block, KV block) grid step of the online softmax.
+
+    Causal steps past the query block's last visible KV block do nothing;
+    ``_init`` and ``_store`` run at the first and last step regardless.
+    """
     qi = pl.program_id(0)
     ki = pl.program_id(1)
 
@@ -51,28 +89,35 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)            # (bq, d)
-    k = k_ref[...].astype(jnp.float32)            # (bk, d)
-    v = v_ref[...].astype(jnp.float32)            # (bk, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                precision=_PRECISION) * scale
+    def _accumulate():
+        q = q_ref[...].astype(jnp.float32)            # (bq, d)
+        k = k_ref[...].astype(jnp.float32)            # (bk, d)
+        v = v_ref[...].astype(jnp.float32)            # (bk, d)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                    precision=_PRECISION) * scale
+
+        if causal:
+            # global positions; query block ends align with KV end (prefix cache)
+            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) \
+                + (sk - sq)
+            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            s = jnp.where(q_pos >= k_pos, s, _NEG)
+
+        m_prev = m_ref[...]                            # (bq, 1)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32, precision=_PRECISION)
+        m_ref[...] = m_new
 
     if causal:
-        # global positions; query block ends align with KV end (prefix cache)
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) \
-            + (sk - sq)
-        k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG)
-
-    m_prev = m_ref[...]                            # (bq, 1)
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32, precision=_PRECISION)
-    m_ref[...] = m_new
+        pl.when(ki <= last_visible_block(qi, sq=sq, sk=sk, bq=bq, bk=bk))(
+            _accumulate)
+    else:
+        _accumulate()
 
     @pl.when(ki == nk - 1)
     def _store():
@@ -101,6 +146,14 @@ def make_flash_attention(Sq: int, Sk: int, D: int,
     kernel = functools.partial(
         _flash_kernel, nk=nk, scale=scale, causal=causal,
         sq=Sq, sk=Sk, bq=bq, bk=bk)
+    if causal:
+        # a skipped step names the block before it again: no copy is issued
+        def kv_index(qi, ki):
+            last = last_visible_block(qi, sq=Sq, sk=Sk, bq=bq, bk=bk)
+            return jnp.minimum(ki, last), 0
+    else:
+        def kv_index(qi, ki):
+            return ki, 0
     kwargs: Dict[str, Any] = {}
     if not interpret:
         # the chip's VMEM budget, the one the static proofs check against
@@ -113,8 +166,8 @@ def make_flash_attention(Sq: int, Sk: int, D: int,
         grid=(Sq // bq, nk),
         in_specs=[
             pl.BlockSpec((bq, D), lambda qi, ki: (qi, 0)),
-            pl.BlockSpec((bk, D), lambda qi, ki: (ki, 0)),
-            pl.BlockSpec((bk, D), lambda qi, ki: (ki, 0)),
+            pl.BlockSpec((bk, D), kv_index),
+            pl.BlockSpec((bk, D), kv_index),
         ],
         out_specs=pl.BlockSpec((bq, D), lambda qi, ki: (qi, 0)),
         out_shape=jax.ShapeDtypeStruct((Sq, D), dtype),

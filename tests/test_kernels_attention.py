@@ -6,8 +6,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.attention import (attention_reference, flash_attention,
-                                     make_flash_attention)
+from repro.kernels.attention import (attention_reference,
+                                     causal_block_counts, flash_attention,
+                                     last_visible_block, make_flash_attention)
 
 RNG = np.random.default_rng(2)
 
@@ -31,14 +32,63 @@ def test_flash_matches_oracle(causal, cfg):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_prefix_cache_alignment():
-    """Sq < Sk: query block ends align with KV end (decode prefill)."""
-    q, k, v = _qkv(128, 512, 64)
-    out = make_flash_attention(128, 512, 64, {"BLOCK_Q": 64, "BLOCK_K": 128},
+@pytest.mark.parametrize("sq,sk,bq,bk,skips", [
+    (128, 512, 64, 128, False),
+    (128, 512, 64, 64, True),
+    (128, 512, 64, 32, True),
+    (512, 128, 64, 64, True),
+])
+def test_prefix_cache_alignment(sq, sk, bq, bk, skips):
+    """Sq < Sk: query block ends align with KV end (decode prefill).
+
+    Sq > Sk builds and runs too; its rows that see no key are outside the
+    contract (the reference gives NaN there), so only the others compare.
+    """
+    q, k, v = _qkv(sq, sk, 64)
+    computed, total = causal_block_counts(sq, sk, bq, bk, True)
+    assert (computed < total) == skips
+    out = make_flash_attention(sq, sk, 64, {"BLOCK_Q": bq, "BLOCK_K": bk},
                                causal=True, interpret=True)(q, k, v)
     ref = attention_reference(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    seen = slice(max(sq - sk, 0), sq)
+    np.testing.assert_allclose(np.asarray(out)[seen], np.asarray(ref)[seen],
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("bq,bk", [(64, 64), (128, 64), (64, 256),
+                                   (256, 128)])
+def test_masked_blocks_neither_computed_nor_fetched(bq, bk):
+    """K and V rows [384, 512) are NaN; a query block whose last visible KV
+    block ends at or before row 384 never touches them, so its rows stay
+    finite and match the reference on the clean data (computing a fully
+    masked block would add 0 * NaN)."""
+    s, poison = 512, 384
+    q, k, v = _qkv(s, s, 64)
+    ref = np.asarray(attention_reference(q, k, v, causal=True))
+    k = k.at[poison:].set(jnp.nan)
+    v = v.at[poison:].set(jnp.nan)
+    out = np.asarray(make_flash_attention(
+        s, s, 64, {"BLOCK_Q": bq, "BLOCK_K": bk}, causal=True,
+        interpret=True)(q, k, v))
+    clean = [qi for qi in range(s // bq)
+             if (int(last_visible_block(qi, sq=s, sk=s, bq=bq, bk=bk)) + 1)
+             * bk <= poison]
+    assert clean
+    rows = np.concatenate([np.arange(qi * bq, (qi + 1) * bq)
+                           for qi in clean])
+    assert np.isfinite(out[rows]).all()
+    np.testing.assert_allclose(out[rows], ref[rows], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,causal,counts", [
+    ((4096, 4096, 512, 1024), True, (20, 32)),
+    ((4096, 4096, 256, 512), True, (72, 128)),
+    ((4096, 4096, 256, 512), False, (128, 128)),
+    ((128, 512, 64, 128), True, (8, 8)),
+    ((128, 512, 64, 64), False, (16, 16)),
+])
+def test_causal_block_counts(shape, causal, counts):
+    assert causal_block_counts(*shape, causal) == counts
 
 
 def test_batched_multihead_wrapper():
