@@ -2,7 +2,8 @@
 
 61L, d_model 7168, 128 heads (MLA), per-expert d_ff 2048, vocab 129280,
 256 routed experts top-8 + 1 shared, first 3 layers dense (d_ff 18432),
-multi-token prediction (1 depth).
+multi-token prediction (1 depth).  Routing (HF config.json): sigmoid scores,
+noaux_tc over 8 groups keeping 4, norm_topk_prob, routed_scaling_factor 2.5.
 """
 
 from ..models.config import ModelConfig
@@ -23,6 +24,10 @@ FULL = ModelConfig(
     num_shared_experts=1,
     moe_first_dense=3,
     router_impl="sigmoid",
+    topk_method="noaux_tc",
+    n_group=8,
+    topk_group=4,
+    routed_scaling_factor=2.5,
     # MLA
     use_mla=True,
     q_lora_rank=1_536,
@@ -49,6 +54,10 @@ SMOKE = ModelConfig(
     num_shared_experts=1,
     moe_first_dense=1,
     router_impl="sigmoid",
+    topk_method="noaux_tc",
+    n_group=4,
+    topk_group=2,
+    routed_scaling_factor=2.5,
     use_mla=True,
     q_lora_rank=64,
     kv_lora_rank=32,

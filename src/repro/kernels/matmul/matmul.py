@@ -61,11 +61,17 @@ def _dtype(name: str):
 # kernel bodies
 # ---------------------------------------------------------------------------
 
-def _mm_kernel_scratch(a_ref, b_ref, o_ref, acc_ref, *, nk: int,
-                       inner_steps: int, acc_dtype, trans_a: bool):
-    """K-accumulation into a VMEM scratch accumulator."""
+def accumulate_k_step(acc_ref, a_ref, b_ref, *, first, inner_steps: int = 1,
+                      acc_dtype=jnp.float32, trans_a: bool = False):
+    """One K step of a tiled product: ``acc_ref += a @ b`` at HIGHEST, with
+    ``acc_ref`` zeroed first where ``first`` (the grid's first K step).
 
-    @pl.when(pl.program_id(2) == 0)
+    The GEMM's tile loop, shared with the kernels built on its tiles
+    (``kernels/moe/grouped.py``).  ``a_ref`` holds a (BM, BK) block, or
+    (BK, BM) when ``trans_a``; ``b_ref`` a (BK, BN) block.
+    """
+
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -88,6 +94,14 @@ def _mm_kernel_scratch(a_ref, b_ref, o_ref, acc_ref, *, nk: int,
                            precision=_PRECISION)
         acc_ref[...] = acc
 
+
+def _mm_kernel_scratch(a_ref, b_ref, o_ref, acc_ref, *, nk: int,
+                       inner_steps: int, acc_dtype, trans_a: bool):
+    """K-accumulation into a VMEM scratch accumulator."""
+    accumulate_k_step(acc_ref, a_ref, b_ref, first=pl.program_id(2) == 0,
+                      inner_steps=inner_steps, acc_dtype=acc_dtype,
+                      trans_a=trans_a)
+
     @pl.when(pl.program_id(2) == nk - 1)
     def _store():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
@@ -96,27 +110,9 @@ def _mm_kernel_scratch(a_ref, b_ref, o_ref, acc_ref, *, nk: int,
 def _mm_kernel_inplace(a_ref, b_ref, o_ref, *, nk: int, inner_steps: int,
                        acc_dtype, trans_a: bool):
     """K-accumulation directly into the output block (ACC_IN_OUTPUT)."""
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    a = a_ref[...]
-    if trans_a:
-        a = a.T
-    b = b_ref[...]
-    if inner_steps == 1:
-        o_ref[...] += jnp.dot(a, b, preferred_element_type=acc_dtype,
-                              precision=_PRECISION)
-    else:
-        step = a.shape[1] // inner_steps
-        acc = o_ref[...]
-        for s in range(inner_steps):
-            acc += jnp.dot(a[:, s * step:(s + 1) * step],
-                           b[s * step:(s + 1) * step, :],
-                           preferred_element_type=acc_dtype,
-                           precision=_PRECISION)
-        o_ref[...] = acc
+    accumulate_k_step(o_ref, a_ref, b_ref, first=pl.program_id(2) == 0,
+                      inner_steps=inner_steps, acc_dtype=acc_dtype,
+                      trans_a=trans_a)
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,12 @@ class ModelConfig:
     moe_first_dense: int = 0          # leading layers with dense FFN (DeepSeek: 3)
     capacity_factor: float = 1.25
     router_impl: str = "softmax"      # softmax | sigmoid (DeepSeek-style)
+    #: greedy: the top k by score | noaux_tc: DeepSeek-V3's group-limited
+    #: choice by score + e_score_correction_bias (a per-expert parameter)
+    topk_method: str = "greedy"
+    n_group: int = 1                  # noaux_tc: groups of experts
+    topk_group: int = 1               # noaux_tc: groups each token keeps
+    routed_scaling_factor: float = 1.0
 
     # -- MLA (DeepSeek latent attention) -------------------------------------------
     use_mla: bool = False

@@ -33,7 +33,12 @@ class RunConfig:
     """Execution knobs (a point in the sharding tuner's space)."""
 
     remat: str = "none"              # none | full | dots
-    moe_impl: str = "scatter"        # scatter | onehot
+    moe_impl: str = "scatter"        # scatter | gather | onehot | grouped
+    #: the routed experts this device holds (expert parallelism): experts
+    #: moe_expert_offset to moe_expert_offset + moe_experts_held of every
+    #: MoE layer; 0 held = all.  A share runs with moe_impl "grouped".
+    moe_expert_offset: int = 0
+    moe_experts_held: int = 0
     attn_chunk: int = 0              # 0 = unchunked; else KV chunk length
     #: attention sharding mode: grouped | expanded (see layers.apply_attention)
     attn_mode: str = "grouped"
@@ -69,14 +74,15 @@ DEFAULT_RUN = RunConfig()
 # parameter trees
 # ---------------------------------------------------------------------------
 
-def _attn_block_defs(cfg: ModelConfig, ffn: str) -> Dict[str, Any]:
+def _attn_block_defs(cfg: ModelConfig, ffn: str,
+                     run: RunConfig = DEFAULT_RUN) -> Dict[str, Any]:
     d = cfg.d_model
     block: Dict[str, Any] = {"ln1": norm_defs(d), "ln2": norm_defs(d)}
     block["attn"] = mla_defs(cfg) if cfg.use_mla else attention_defs(cfg)
     if ffn == "dense":
         block["mlp"] = mlp_defs(cfg)
     elif ffn == "moe":
-        block["moe"] = moe_defs(cfg)
+        block["moe"] = moe_defs(cfg, run.moe_experts_held)
     else:
         raise ValueError(ffn)
     return block
@@ -86,7 +92,8 @@ def _mamba_block_defs(cfg: ModelConfig) -> Dict[str, Any]:
     return {"ln": norm_defs(cfg.d_model), "mamba": mamba_defs(cfg)}
 
 
-def model_defs(cfg: ModelConfig) -> Dict[str, Any]:
+def model_defs(cfg: ModelConfig, run: RunConfig = DEFAULT_RUN) -> Dict[str, Any]:
+    """The parameter tree; ``run`` says which routed experts are held."""
     d, V = cfg.d_model, cfg.vocab_size
     defs: Dict[str, Any] = {
         "embed": ParamDef((V, d), ("vocab", "embed"), init="normal",
@@ -114,11 +121,12 @@ def model_defs(cfg: ModelConfig) -> Dict[str, Any]:
         if n_dense:
             defs["dense_blocks"] = stack_defs(
                 _attn_block_defs(cfg, "dense"), n_dense)
-        defs["moe_blocks"] = stack_defs(_attn_block_defs(cfg, "moe"), n_moe)
+        defs["moe_blocks"] = stack_defs(_attn_block_defs(cfg, "moe", run),
+                                        n_moe)
         if cfg.mtp_depth:
             defs["mtp"] = {
                 "proj": ParamDef((2 * d, d), (None, "embed")),
-                "block": _attn_block_defs(cfg, "moe"),
+                "block": _attn_block_defs(cfg, "moe", run),
                 "norm": norm_defs(d),
             }
     else:  # dense / vlm / audio
@@ -127,12 +135,12 @@ def model_defs(cfg: ModelConfig) -> Dict[str, Any]:
     return defs
 
 
-def init_model(cfg: ModelConfig, key: jax.Array):
-    return init_params(model_defs(cfg), key, cfg.param_dtype)
+def init_model(cfg: ModelConfig, key: jax.Array, run: RunConfig = DEFAULT_RUN):
+    return init_params(model_defs(cfg, run), key, cfg.param_dtype)
 
 
-def abstract_model(cfg: ModelConfig):
-    return abstract_params(model_defs(cfg), cfg.param_dtype)
+def abstract_model(cfg: ModelConfig, run: RunConfig = DEFAULT_RUN):
+    return abstract_params(model_defs(cfg, run), cfg.param_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -156,7 +164,8 @@ def _attn_block(cfg: ModelConfig, run: RunConfig, p, x, positions,
     if ffn == "dense":
         out, aux = apply_mlp(p["mlp"], h), jnp.zeros((), jnp.float32)
     else:
-        out, aux = apply_moe(cfg, p["moe"], h, impl=run.moe_impl)
+        out, aux = apply_moe(cfg, p["moe"], h, impl=run.moe_impl,
+                             expert_offset=run.moe_expert_offset)
     return x + out, aux, new_cache
 
 

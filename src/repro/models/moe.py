@@ -12,6 +12,14 @@ experts over model, so expert compute is fully parallel.  A second
 implementation (MOE_IMPL='onehot') keeps the classic einsum dispatch for
 small expert counts — it is both the smoke-test oracle and a point in the
 sharding tuner's space.
+
+``impl="grouped"`` is dropless: the tuned grouped SwiGLU kernel
+(``repro.kernels.moe``, resolved through the registry) computes the routed
+pairs of the experts this layer holds.  Under expert parallelism a layer
+holds experts ``expert_offset`` to ``expert_offset + E_held`` of all E
+(``RunConfig.moe_expert_offset`` / ``moe_experts_held``): it routes over
+all E and computes its own experts' part of the result, which the absent
+experts' parts would complete.
 """
 
 from __future__ import annotations
@@ -28,14 +36,20 @@ from .layers import apply_mlp, mlp_defs
 from .params import ParamDef
 
 
-def moe_defs(cfg: ModelConfig) -> Dict[str, Any]:
+def moe_defs(cfg: ModelConfig, experts: int = 0) -> Dict[str, Any]:
+    """The layer's parameters; ``experts`` routed experts held (0: all)."""
     d, E, m = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    held = experts or E
     defs: Dict[str, Any] = {
         "router": ParamDef((d, E), ("embed", "experts"), scale=0.1),
-        "wg": ParamDef((E, d, m), ("experts", "embed", "expert_mlp")),
-        "wi": ParamDef((E, d, m), ("experts", "embed", "expert_mlp")),
-        "wo": ParamDef((E, m, d), ("experts", "expert_mlp", "embed")),
+        "wg": ParamDef((held, d, m), ("experts", "embed", "expert_mlp")),
+        "wi": ParamDef((held, d, m), ("experts", "embed", "expert_mlp")),
+        "wo": ParamDef((held, m, d), ("experts", "expert_mlp", "embed")),
     }
+    if cfg.topk_method == "noaux_tc":
+        # e_score_correction_bias: set by load balancing, not by gradients
+        defs["router_bias"] = ParamDef((E,), ("experts",), init="zeros",
+                                       dtype="float32")
     if cfg.num_shared_experts:
         defs["shared"] = mlp_defs(
             cfg, d_ff=cfg.moe_d_ff * cfg.num_shared_experts)
@@ -48,17 +62,36 @@ def capacity(cfg: ModelConfig, seq_len: int) -> int:
     return max(4, -(-c // 4) * 4)         # round up to a multiple of 4
 
 
+def _noaux_tc_choice(cfg: ModelConfig, select):
+    """DeepSeek-V3's group-limited choice: each of ``n_group`` groups of
+    experts scores the sum of its top 2 selection scores, the top
+    ``topk_group`` groups are kept, and the top k experts by selection
+    score within them are chosen.  Returns their ids."""
+    *lead, E = select.shape
+    grouped = select.reshape(*lead, cfg.n_group, E // cfg.n_group)
+    group_score = lax.top_k(grouped, 2)[0].sum(-1)
+    _, top_groups = lax.top_k(group_score, cfg.topk_group)
+    kept = jnp.any(top_groups[..., None] == jnp.arange(cfg.n_group), axis=-2)
+    masked = jnp.where(kept[..., None], grouped, -jnp.inf)
+    return lax.top_k(masked.reshape(*lead, E), cfg.experts_per_token)[1]
+
+
 def _router(cfg: ModelConfig, p, x):
     """Return (weights, indices): (B, S, k) routing weights and expert ids."""
     logits = jnp.einsum("bsd,de->bse", x, p["router"]).astype(jnp.float32)
     if cfg.router_impl == "sigmoid":       # DeepSeek-V3 style
         scores = jax.nn.sigmoid(logits)
-        topv, topi = lax.top_k(scores, cfg.experts_per_token)
+        if cfg.topk_method == "noaux_tc":
+            # chosen by score + bias, weighted by the score alone
+            topi = _noaux_tc_choice(cfg, scores + p["router_bias"])
+            topv = jnp.take_along_axis(scores, topi, axis=-1)
+        else:
+            topv, topi = lax.top_k(scores, cfg.experts_per_token)
         topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
         topv, topi = lax.top_k(probs, cfg.experts_per_token)
-    return topv, topi, logits
+    return topv * cfg.routed_scaling_factor, topi, logits
 
 
 def _aux_loss(cfg: ModelConfig, logits, topi) -> jax.Array:
@@ -186,11 +219,35 @@ def _dispatch_onehot(cfg: ModelConfig, p, x, topv, topi):
     return jnp.einsum("bskd,bsk->bsd", gathered, weights.astype(x.dtype))
 
 
+def _experts_grouped(cfg: ModelConfig, p, x, topv, topi, expert_offset):
+    """Dropless: the held experts' part of the result, from the grouped
+    SwiGLU op, whose tiles ``registry.lookup`` resolves."""
+    from ..kernels.moe import moe_experts
+
+    B, S, d = x.shape
+    k = cfg.experts_per_token
+    out = moe_experts(x.reshape(B * S, d), topi.reshape(B * S, k),
+                      topv.reshape(B * S, k), p["wg"], p["wi"], p["wo"],
+                      expert_offset=expert_offset,
+                      num_experts=cfg.num_experts,
+                      interpret=jax.default_backend() != "tpu")
+    return out.reshape(B, S, d)
+
+
 def apply_moe(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
-              impl: str = "scatter") -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out, aux_loss)."""
+              impl: str = "scatter",
+              expert_offset: int = 0) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, d) -> (out, aux_loss).  ``p`` holds experts
+    ``expert_offset`` onward, as many as its ``wg`` stacks; only the
+    ``grouped`` impl computes a share of the experts."""
     topv, topi, logits = _router(cfg, p, x)
-    if impl == "scatter":
+    if impl != "grouped" and p["wg"].shape[0] != cfg.num_experts:
+        raise ValueError(f"MoE impl {impl!r} computes all "
+                         f"{cfg.num_experts} experts; this layer holds "
+                         f"{p['wg'].shape[0]} (use impl='grouped')")
+    if impl == "grouped":
+        routed = _experts_grouped(cfg, p, x, topv, topi, expert_offset)
+    elif impl == "scatter":
         routed = _dispatch_scatter(cfg, p, x, topv, topi)
     elif impl == "gather":
         routed = _dispatch_gather(cfg, p, x, topv, topi)

@@ -1,5 +1,7 @@
 """Model math: SSD oracle, decode parity, MoE dispatch equivalence, rope."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -151,6 +153,150 @@ def test_moe_shared_expert_contributes():
     p0 = jax.tree_util.tree_map(jnp.zeros_like, p["shared"])
     out0, _ = apply_moe(cfg, {**p, "shared": p0}, x)
     assert not np.allclose(np.asarray(out), np.asarray(out0))
+
+
+def _noaux_cfg(**kw):
+    base = dict(name="noaux-test", family="moe", num_layers=1, d_model=32,
+                vocab_size=64, num_heads=2, num_kv_heads=2, head_dim=16,
+                num_experts=16, experts_per_token=4, moe_d_ff=16,
+                router_impl="sigmoid", topk_method="noaux_tc", n_group=4,
+                topk_group=2, routed_scaling_factor=2.5)
+    base.update(kw)
+    return ModelConfig(**base)
+
+
+def _plain_noaux_tc(logits, bias, n_group, topk_group, k, factor):
+    """DeepSeek-V3's router, token by token in numpy."""
+    ids, weights = [], []
+    for row in np.asarray(logits, np.float64).reshape(-1, logits.shape[-1]):
+        s = 1.0 / (1.0 + np.exp(-row))
+        select = s + np.asarray(bias, np.float64)
+        groups = select.reshape(n_group, -1)
+        group_score = np.sort(groups, axis=1)[:, -2:].sum(axis=1)
+        kept = np.argsort(-group_score, kind="stable")[:topk_group]
+        size = groups.shape[1]
+        candidates = [e for g in kept for e in range(g * size, (g + 1) * size)]
+        chosen = sorted(candidates, key=lambda e: -select[e])[:k]
+        w = s[chosen]
+        ids.append(chosen)
+        weights.append(w / w.sum() * factor)
+    return np.array(ids), np.array(weights)
+
+
+def test_noaux_tc_routing_matches_plain_implementation():
+    """s = sigmoid(x W_r); groups by the sum of their top 2 of s + b; the top
+    k of s + b within the kept groups; weights s, normalised, times 2.5."""
+    from repro.models.moe import _router
+
+    cfg = _noaux_cfg()
+    p = init_params(moe_defs(cfg), jax.random.PRNGKey(3), "float32")
+    p["router_bias"] = jnp.asarray(RNG.normal(size=16) * 0.05, jnp.float32)
+    x = jnp.asarray(RNG.normal(size=(2, 24, 32)), jnp.float32)
+    topv, topi, logits = _router(cfg, p, x)
+    ids, weights = _plain_noaux_tc(logits, p["router_bias"], 4, 2, 4, 2.5)
+    got = np.asarray(topi).reshape(-1, 4)
+    order = np.argsort(got, axis=1)
+    np.testing.assert_array_equal(np.take_along_axis(got, order, 1),
+                                  np.sort(ids, axis=1))
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(topv).reshape(-1, 4), order, 1),
+        np.take_along_axis(weights, np.argsort(ids, axis=1), 1), rtol=1e-5)
+    # the bias moves the choice: without it, some token chooses otherwise
+    ids0, _ = _plain_noaux_tc(logits, np.zeros(16), 4, 2, 4, 2.5)
+    assert not np.array_equal(np.sort(ids0, 1), np.sort(ids, 1))
+
+
+def _router_before(cfg, p, x):
+    """The router as it was before noaux_tc, the reference for the default
+    fields."""
+    from jax import lax
+
+    logits = jnp.einsum("bsd,de->bse", x, p["router"]).astype(jnp.float32)
+    if cfg.router_impl == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        topv, topi = lax.top_k(scores, cfg.experts_per_token)
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        topv, topi = lax.top_k(probs, cfg.experts_per_token)
+    return topv, topi, logits
+
+
+@pytest.mark.parametrize("router_impl", ["sigmoid", "softmax"])
+def test_router_defaults_route_as_before(router_impl):
+    """No groups, no bias and a factor of 1.0: every other configuration's
+    routing is bit for bit what it was."""
+    from repro.models.moe import _router
+
+    cfg = dataclasses.replace(_moe_cfg(), router_impl=router_impl)
+    p = init_params(moe_defs(cfg), jax.random.PRNGKey(4), "float32")
+    assert "router_bias" not in p
+    x = jnp.asarray(RNG.normal(size=(2, 16, 32)), jnp.float32)
+    for got, want in zip(jax.jit(lambda x: _router(cfg, p, x))(x),
+                         jax.jit(lambda x: _router_before(cfg, p, x))(x)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("router_impl", ["softmax", "noaux_tc"])
+def test_moe_grouped_matches_scatter_without_drops(router_impl):
+    """The dropless grouped op against scatter dispatch, at a capacity no
+    expert overflows."""
+    cfg = (_moe_cfg(cf=8.0) if router_impl == "softmax"
+           else _noaux_cfg(capacity_factor=16.0))
+    p = init_params(moe_defs(cfg), jax.random.PRNGKey(5), "float32")
+    x = jnp.asarray(RNG.normal(size=(2, 16, 32)) * 0.3, jnp.float32)
+    out_s, aux_s = apply_moe(cfg, p, x, impl="scatter")
+    out_g, aux_g = apply_moe(cfg, p, x, impl="grouped")
+    np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_s),
+                               rtol=1e-5, atol=1e-6)
+    assert float(aux_g) == float(aux_s)
+
+
+def test_moe_expert_shares_add_up_to_the_layer():
+    """Expert parallelism's cut: each share holds E_held of the E experts,
+    routes over all E and computes its own experts' part; the shares'
+    parts, with the shared expert (which every share computes alike)
+    counted once, add up to the uncut layer."""
+    from repro.models.layers import apply_mlp
+
+    cfg = _noaux_cfg(num_shared_experts=1, capacity_factor=16.0)
+    p = init_params(moe_defs(cfg), jax.random.PRNGKey(6), "float32")
+    p["router_bias"] = jnp.asarray(RNG.normal(size=16) * 0.05, jnp.float32)
+    x = jnp.asarray(RNG.normal(size=(2, 16, 32)) * 0.3, jnp.float32)
+    whole, _ = apply_moe(cfg, p, x, impl="scatter")
+    shared = apply_mlp(p["shared"], x)
+    held = 4
+    total = shared
+    for offset in range(0, 16, held):
+        share = {**p, **{w: p[w][offset:offset + held]
+                         for w in ("wg", "wi", "wo")}}
+        out, _ = apply_moe(cfg, share, x, impl="grouped",
+                           expert_offset=offset)
+        total = total + (out - shared)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="grouped"):
+        apply_moe(cfg, share, x, impl="scatter")
+
+
+def test_model_runs_one_share_of_its_experts():
+    """RunConfig states the experts a device holds: the parameter tree holds
+    only those, and the model's MoE layers compute their part."""
+    from repro.models import forward, init_model, model_defs
+    from repro.models.model import RunConfig
+
+    cfg = get_config("deepseek-v3-671b", smoke=True)
+    run = RunConfig(moe_impl="grouped", moe_expert_offset=4,
+                    moe_experts_held=4)
+    defs = model_defs(cfg, run)
+    assert defs["moe_blocks"]["moe"]["wg"].shape[1] == 4
+    assert defs["moe_blocks"]["moe"]["router"].shape[-1] == cfg.num_experts
+    params = init_model(cfg, jax.random.PRNGKey(7), run)
+    tokens = jnp.asarray(RNG.integers(0, cfg.vocab_size, (1, 8)), jnp.int32)
+    logits, _ = jax.jit(lambda p, t: forward(cfg, p, {"tokens": t}, run))(
+        params, tokens)
+    assert logits.shape == (1, 8, cfg.vocab_size)
+    assert np.isfinite(np.asarray(logits, np.float32)).all()
 
 
 # ---------------------------------------------------------------------------

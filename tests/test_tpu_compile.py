@@ -1,4 +1,4 @@
-"""The three Pallas kernels compile for a TPU v5e at their deployment shapes.
+"""The four Pallas kernels compile for a TPU v5e at their deployment shapes.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology.  Everything built from the topology is built inside a fixture or
@@ -21,6 +21,9 @@ FLASH_D128 = {"Sq": 4096, "Sk": 4096, "D": 128, "causal": True}
 FLASH_D64 = {"Sq": 4096, "Sk": 4096, "D": 64, "causal": True}
 CONV3 = {"H": 4096, "W": 4096, "Fh": 3, "Fw": 3}
 CONV7 = {"H": 4096, "W": 4096, "Fh": 7, "Fw": 7}
+#: DeepSeek-V3's routed experts as one chip of 32 holds them
+MOE_DSV3 = {"N": 32768, "d": 7168, "m": 2048, "E": 256, "E_held": 8, "k": 8,
+            "dtype": "float32"}
 
 
 @pytest.fixture(scope="module")
@@ -57,17 +60,20 @@ def _compile(kernel, shape, config, sharding):
     return fn.lower(*specs).compile()
 
 
-#: each kernel's Pallas name, which the compiled program and the device
+#: each kernel's Pallas names, which the compiled program and the device
 #: trace carry whatever the configuration
-KERNEL_NAMES = {"gemm": "gemm", "flash_attention": "flash_attention",
-                "conv2d": "conv2d"}
+KERNEL_NAMES = {"gemm": ("gemm",), "flash_attention": ("flash_attention",),
+                "conv2d": ("conv2d",),
+                "moe_experts": ("moe_experts_gate_up", "moe_experts_down",
+                                "moe_experts_combine")}
 
 
 def _assert_named_kernel(kernel, compiled):
     calls = [line for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls, "no Pallas kernel in the compiled program"
-    assert any(f"%{KERNEL_NAMES[kernel]}." in line for line in calls), calls
+    for name in KERNEL_NAMES[kernel]:
+        assert any(f"%{name}." in line for line in calls), calls
 
 
 def _heuristic(kernel, shape):
@@ -88,9 +94,10 @@ def _conv(unroll):
     ("conv2d", CONV3, _conv(False)),
     ("conv2d", CONV7, _conv(True)),
     ("conv2d", CONV7, _conv(False)),
+    ("moe_experts", MOE_DSV3, None),
 ], ids=["gemm-2048", "gemm-granite-mlp", "flash-d128", "flash-d64",
         "conv3x3-unrolled", "conv3x3-rolled", "conv7x7-unrolled",
-        "conv7x7-rolled"])
+        "conv7x7-rolled", "moe-deepseek-v3"])
 def test_kernel_compiles_for_v5e(one_chip, kernel, shape, config):
     config = config or _heuristic(kernel, shape)
     compiled = _compile(kernel, shape, config, one_chip)
@@ -101,11 +108,13 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape, config):
     ("gemm", GEMM, {"BLOCK_M": 1024, "BLOCK_N": 1024, "BLOCK_K": 1024}),
     ("flash_attention", FLASH_D128, {"BLOCK_Q": 1024, "BLOCK_K": 2048,
                                      "PIPELINE_DEPTH": 2}),
-], ids=["gemm-1024-blocks", "flash-1024x2048"])
+    ("moe_experts", MOE_DSV3, {"BLOCK_M": 512, "BLOCK_N": 1024,
+                               "BLOCK_K": 1024}),
+], ids=["gemm-1024-blocks", "flash-1024x2048", "moe-largest-blocks"])
 def test_vmem_budget_agrees_with_analyzer(one_chip, kernel, shape, config):
     """The largest blocks of the spaces fit the profile's VMEM by the
     declared footprint, and the compiler, given the same budget, agrees
-    (its default scoped limit of 16 MiB refused both)."""
+    (its default scoped limit of 16 MiB refused the first two)."""
     k = resolve(kernel)
     config = dict(_heuristic(kernel, shape), **config)
     assert TPU_V5E.fits_vmem(k.vmem_footprint(shape, config))
