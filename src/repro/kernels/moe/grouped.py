@@ -35,9 +35,14 @@ whatever the load, and no buffer is sized for the worst case of N * k rows.
 the permuted copy of x, but one DMA per row of width BLOCK_K is far below
 the DMA engine's efficient size.)
 
-Combine.  HBM holds a float32 (rows, d) array in tiles of 8 rows, so one
-row cannot be copied alone: the combine kernel copies each pair's aligned
-8-row block of y, for a block of tokens, and keeps the pair's row of it.
+Combine.  Each step of the combine kernel writes a block of tokens, whose
+slots mostly hold no pair computed here.  A sort along each block's slots
+first puts the live pairs ahead, in token order, and counts them; the
+kernel walks that count alone.  HBM holds a float32 (rows, d) array in
+tiles of 8 rows, so one row cannot be copied alone: the kernel copies each
+pair's aligned 8-row block of y, keeps a ring of ``_RING`` such copies in
+flight while it adds earlier pairs, and adds each pair's row, rolled to its
+token's sublane, into the token's aligned 8-row group of the output.
 """
 
 from __future__ import annotations
@@ -48,6 +53,7 @@ from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -66,8 +72,8 @@ COMBINE_NAME = "moe_experts_combine"
 #: a 1-D int32 or float32 array lies in HBM in tiles of this many entries,
 #: so a step's SMEM block of its pairs' rows and weights is whole tiles
 _SMEM_TILE = 1024
-#: tokens the combine kernel gathers at once
-_CHUNK = 16
+#: 8-row copies of y the combine kernel keeps in flight
+_RING = 16
 #: rows in one tile of a float32 array in HBM
 _SUBLANES = 8
 
@@ -92,6 +98,23 @@ def grouped_tile_counts(group_sizes: Sequence[int], block_m: int,
     if grid < computed:
         raise ValueError(f"{computed} tiles do not fit a grid of {grid}")
     return computed, grid - computed, padded
+
+
+def combine_pair_counts(pair_rows: Sequence[int],
+                        k: int) -> Tuple[int, int, int]:
+    """(pairs walked, slots skipped, blocks with no pair) of one combine
+    call: ``pair_rows`` holds each of the N * k (token, expert) slots' row
+    of y, token-major, and -1 for a slot with none in this round.  The
+    kernel walks a block's live pairs alone; the slots of the padding
+    tokens that fill the last block count as skipped."""
+    rows = np.asarray(pair_rows).reshape(-1)
+    slots = combine_tokens(k) * k
+    blocks = -(-rows.size // slots)
+    live = np.zeros(blocks * slots, bool)
+    live[:rows.size] = rows >= 0
+    per_block = live.reshape(blocks, slots).sum(axis=1)
+    walked = int(per_block.sum())
+    return walked, blocks * slots - walked, int((per_block == 0).sum())
 
 
 def round_rows(N: int, k: int, E: int, groups: int, block_m: int) -> int:
@@ -196,66 +219,53 @@ def _grouped_call(body, name: str, *, tiles: int, K: int, N: int,
         **kwargs)
 
 
-def _combine_kernel(rows_ref, w_ref, y_hbm, *refs, k: int, accumulate: bool):
-    """One block of tokens: out = prev (or zero) + each token's pairs'
-    ``w * y[row]``.  ``rows_ref``/``w_ref`` (SMEM) hold the block's pairs,
-    token-major, with row -1 for a pair not computed in this round.  The
-    tokens go ``_CHUNK`` at a time: copy their pairs' 8-row blocks of y,
-    then keep each pair's row of its block."""
+def _combine_kernel(count_ref, slots_ref, rows_ref, w_ref, y_hbm, *refs,
+                    k: int, accumulate: bool):
+    """One block of tokens: out = prev (or zero) + each live pair's
+    ``w * y[row]``, added into its token's row.  ``count_ref`` (scalar
+    prefetch) holds each block's count of live pairs, ``slots_ref`` (SMEM)
+    the block's slots with the live ones first, in slot order, which is
+    token order; ``rows_ref`` and ``w_ref`` (SMEM) each slot's row of y and
+    weight.  Only the live pairs are walked: a ring of ``_RING`` buffers
+    keeps the next pairs' 8-row blocks of y in flight while earlier ones
+    are added."""
     if accumulate:
-        prev_ref, out_ref, buf, stage, chunk_out, sem = refs
+        prev_ref, out_ref, buf, sem = refs
         out_ref[...] = prev_ref[...]
     else:
-        out_ref, buf, stage, chunk_out, sem = refs
+        out_ref, buf, sem = refs
         out_ref[...] = jnp.zeros_like(out_ref)
-    n = _CHUNK * k                           # pairs in one chunk
-    sublane = lax.broadcasted_iota(jnp.int32, stage.shape, 0)
+    n = count_ref[pl.program_id(0)]
+    sublane = lax.broadcasted_iota(jnp.int32, buf.shape[1:], 0)
 
-    def one_chunk(c, carry):
-        base = c * n
+    def copy(j):
+        slot = lax.rem(j, _RING)
+        row = rows_ref[slots_ref[j]]
+        return pltpu.make_async_copy(y_hbm.at[lax.div(row, _SUBLANES)],
+                                     buf.at[slot], sem.at[slot])
 
-        def copy(j):
-            return pltpu.make_async_copy(
-                y_hbm.at[rows_ref[base + j] // _SUBLANES], buf.at[j], sem)
+    for j in range(_RING - 1):
+        pl.when(j < n)(lambda j=j: copy(j).start())
 
-        def start(j, carry):
-            pl.when(rows_ref[base + j] >= 0)(lambda: copy(j).start())
-            return carry
+    def add(j, carry):
+        @pl.when(j + _RING - 1 < n)
+        def _next():
+            copy(j + _RING - 1).start()
 
-        def wait(j, carry):
-            pl.when(rows_ref[base + j] >= 0)(lambda: copy(j).wait())
-            return carry
-
-        lax.fori_loop(0, n, start, 0)
-        lax.fori_loop(0, n, wait, 0)
-        chunk_out[...] = jnp.zeros_like(chunk_out)
-        for t in range(_CHUNK):
-            live = functools.reduce(jnp.logical_or, [
-                rows_ref[base + t * k + i] >= 0 for i in range(k)])
-
-            @pl.when(live)
-            def _token():
-                stage[...] = jnp.zeros_like(stage)
-
-                def add(i, carry):
-                    j = t * k + i
-
-                    @pl.when(rows_ref[base + j] >= 0)
-                    def _pair():
-                        stage[...] += jnp.where(
-                            sublane == rows_ref[base + j] % _SUBLANES,
-                            w_ref[base + j] * buf[j], 0.0)
-                    return carry
-
-                lax.fori_loop(0, k, add, 0)
-                chunk_out[t:t + 1, :] = jnp.sum(stage[...], axis=0,
-                                                keepdims=True)
-
-        rows = pl.ds(pl.multiple_of(c * _CHUNK, _CHUNK), _CHUNK)
-        out_ref[rows, :] += chunk_out[...]
+        copy(j).wait()
+        slot = slots_ref[j]
+        tok = lax.div(slot, k)
+        sub = lax.rem(tok, _SUBLANES)
+        # move the pair's row of its block to its token's sublane
+        shift = lax.rem(sub - lax.rem(rows_ref[slot], _SUBLANES) + _SUBLANES,
+                        _SUBLANES)
+        row = pltpu.roll(buf[lax.rem(j, _RING)], shift, 0)
+        group = pl.ds(pl.multiple_of(tok - sub, _SUBLANES), _SUBLANES)
+        out_ref[group, :] += jnp.where(sublane == sub, w_ref[slot] * row,
+                                       0.0)
         return carry
 
-    lax.fori_loop(0, out_ref.shape[0] // _CHUNK, one_chunk, 0)
+    lax.fori_loop(0, n, add, 0)
 
 
 def combine_tokens(k: int) -> int:
@@ -266,16 +276,18 @@ def combine_tokens(k: int) -> int:
 
 def _combine_call(N: int, d: int, k: int, *, accumulate: bool,
                   interpret: bool):
-    """pallas_call of the combine: takes (rows, weights) of every pair (N * k,
-    token-major), y as (rows / 8, 8, d), and with ``accumulate`` the output
-    so far, which it updates in place; returns (N, d) float32."""
+    """pallas_call of the combine: takes each block's count of live pairs
+    and its slots, live first (``_live_first``), every pair's row and
+    weight (N * k, token-major), y as (rows / 8, 8, d), and with
+    ``accumulate`` the output so far, which it updates in place; returns
+    (N, d) float32."""
     bt = combine_tokens(k)
-    pairs = pl.BlockSpec((bt * k,), lambda t: (t,),
+    pairs = pl.BlockSpec((bt * k,), lambda t, count: (t,),
                          memory_space=pltpu.SMEM)
-    tokens = pl.BlockSpec((bt, d), lambda t: (t, 0))
+    tokens = pl.BlockSpec((bt, d), lambda t, count: (t, 0))
     kwargs: Dict[str, Any] = {}
     if accumulate:
-        kwargs["input_output_aliases"] = {3: 0}
+        kwargs["input_output_aliases"] = {5: 0}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -283,17 +295,48 @@ def _combine_call(N: int, d: int, k: int, *, accumulate: bool,
     return pl.pallas_call(
         functools.partial(_combine_kernel, k=k, accumulate=accumulate),
         name=COMBINE_NAME,
-        grid=(N // bt,),
-        in_specs=[pairs, pairs, pl.BlockSpec(memory_space=pl.ANY)]
-        + [tokens] * accumulate,
-        out_specs=tokens,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N // bt,),
+            in_specs=[pairs] * 3 + [pl.BlockSpec(memory_space=pl.ANY)]
+            + [tokens] * accumulate,
+            out_specs=tokens,
+            scratch_shapes=[pltpu.VMEM((_RING, _SUBLANES, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA((_RING,))]),
         out_shape=jax.ShapeDtypeStruct((N, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((_CHUNK * k, _SUBLANES, d), jnp.float32),
-                        pltpu.VMEM((_SUBLANES, d), jnp.float32),
-                        pltpu.VMEM((_CHUNK, d), jnp.float32),
-                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
         **kwargs)
+
+
+def _live_first(pair_row, k: int):
+    """(count, slots) for the combine: each block of ``combine_tokens(k)``
+    tokens' count of pairs with a row (>= 0), and its slots with those
+    first, in slot order."""
+    slots = combine_tokens(k) * k
+    live = pair_row.reshape(-1, slots) >= 0
+    slot = lax.broadcasted_iota(jnp.int32, live.shape, 1)
+    order = lax.sort(jnp.where(live, slot, slot + slots), dimension=1)
+    return (jnp.sum(live, axis=1, dtype=jnp.int32),
+            lax.rem(order, slots).reshape(-1))
+
+
+def make_combine(N: int, d: int, k: int, *, interpret: bool = False):
+    """Return fn(pair_row, pair_weight, y, out=None) -> (N, d) float32:
+    ``out`` (zero where None) plus, for each of the N * k pairs (token-major)
+    with a row of ``y`` (``pair_row`` >= 0), ``pair_weight * y[pair_row]``
+    in its token's row, each token's pairs added in slot order.  N is a
+    whole number of ``combine_tokens(k)``."""
+    first, more = (_combine_call(N, d, k, accumulate=acc,
+                                 interpret=interpret)
+                   for acc in (False, True))
+
+    def fn(pair_row, pair_weight, y, out=None):
+        args = _live_first(pair_row, k) + (
+            pair_row, pair_weight,
+            y.astype(jnp.float32).reshape(-1, _SUBLANES, d))
+        return first(*args) if out is None else more(*args, out)
+
+    return fn
 
 
 # ---------------------------------------------------------------------------
@@ -341,9 +384,7 @@ def make_moe_experts(N: int, d: int, m: int, E: int, E_held: int, k: int,
                             weights=2, **common)
     down = _grouped_call(_down_kernel, DOWN_NAME, K=m, N=d, weights=1,
                          **common)
-    combine_first, combine_more = (
-        _combine_call(Np, d, k, accumulate=acc, interpret=interpret)
-        for acc in (False, True))
+    combine = make_combine(Np, d, k, interpret=interpret)
 
     def fn(x, ids, weights, wg, wi, wo, expert_offset=0):
         with jax.named_scope("repro.moe.permute"):
@@ -389,13 +430,8 @@ def make_moe_experts(N: int, d: int, m: int, E: int, E_held: int, k: int,
                 live = held & (rank >= lo) & (rank < lo + C)
                 pair_row = jnp.where(live, pad_start[pair_group] + rank
                                      - first[pair_group], -1)
-                rows = y.astype(jnp.float32).reshape(R // _SUBLANES,
-                                                     _SUBLANES, d)
-                args = (jnp.pad(pair_row, pad, constant_values=-1),
-                        pair_weight, rows)
-                if out is None:
-                    return combine_first(*args)
-                return combine_more(*args, out)
+                return combine(jnp.pad(pair_row, pad, constant_values=-1),
+                               pair_weight, y, out)
 
         out = one_round(0, None)                  # zero pairs: zero output
         rounds = (ends[-1] + C - 1) // C
@@ -411,13 +447,11 @@ def make_moe_experts(N: int, d: int, m: int, E: int, E_held: int, k: int,
 
 def combine_vmem(d: int, k: int) -> int:
     """Bytes of VMEM the combine kernel claims, whatever the configuration:
-    the 8-row blocks of y for one chunk's pairs, the staging and chunk
-    rows, and the double-buffered float32 token blocks of the output and
-    of the output so far.  ``make_moe_experts`` checks it against the
-    kernels' VMEM limit; ``vmem_footprint`` leaves it out, since no
-    configuration changes it."""
-    scratch = (_CHUNK * k * _SUBLANES + _SUBLANES + _CHUNK) * d
-    return 4 * (scratch + 2 * 2 * combine_tokens(k) * d)
+    its ring of 8-row blocks of y, and the double-buffered float32 token
+    blocks of the output and of the output so far.  ``make_moe_experts``
+    checks it against the kernels' VMEM limit; ``vmem_footprint`` leaves it
+    out, since no configuration changes it."""
+    return 4 * (_RING * _SUBLANES * d + 2 * 2 * combine_tokens(k) * d)
 
 
 def vmem_footprint(config: Config, elt_bytes: int = 4) -> int:

@@ -8,9 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.moe import (MOE_EXPERTS, grid_tiles, grouped_tile_counts,
-                               heuristic_config, make_moe_experts,
-                               moe_experts, moe_experts_reference, round_rows)
+from repro.kernels.moe import (MOE_EXPERTS, combine_pair_counts, grid_tiles,
+                               grouped_tile_counts, heuristic_config,
+                               make_moe_experts, moe_experts,
+                               moe_experts_reference, round_rows)
+from repro.kernels.moe import grouped
+from repro.kernels.moe.grouped import combine_tokens
 
 RNG = np.random.default_rng(15)
 
@@ -160,27 +163,115 @@ def test_heuristic_at_the_published_shape():
 
 
 def test_combine_vmem_is_checked_at_build():
-    """The combine kernel, which every configuration runs, claims ~45 MB
-    of VMEM at the published widths, more than either grouped kernel at
-    any configuration of the space: a v5e holds it, and a build against a
-    smaller limit is refused before anything compiles."""
+    """The combine kernel, which every configuration runs, claims ~18 MB
+    of VMEM at the published widths: more than the heuristic's grouped
+    kernels and less than the space's largest blocks.  A v5e holds it, and
+    a build against a smaller limit is refused before anything compiles."""
     from unittest import mock
 
     from repro.core.profiles import TPU_V5E
-    from repro.kernels.moe import combine_vmem, grouped
+    from repro.kernels.moe import combine_vmem
 
     shape = {"N": 32768, "d": 7168, "m": 2048, "E": 256, "E_held": 8,
              "k": 8, "dtype": "float32"}
     combine = combine_vmem(7168, 8)
-    assert combine == 4 * 7168 * (16 * 8 * 8 + 8 + 16 + 4 * 128)
+    assert combine == 4 * 7168 * (16 * 8 + 4 * 128)
     assert TPU_V5E.fits_vmem(combine)
-    assert all(MOE_EXPERTS.vmem_footprint(shape, cfg) < combine
-               for cfg in MOE_EXPERTS.make_space(shape))
     cfg = heuristic_config(32768, 7168, 2048, 256, 8, 8)
+    assert MOE_EXPERTS.vmem_footprint(shape, cfg) < combine < max(
+        MOE_EXPERTS.vmem_footprint(shape, c)
+        for c in MOE_EXPERTS.make_space(shape))
     with mock.patch.object(grouped, "kernel_vmem_limit",
                            lambda: 16 * 2**20):
         with pytest.raises(ValueError, match="combine kernel needs"):
             make_moe_experts(32768, 7168, 2048, 256, 8, 8, cfg)
+
+
+#: the combine's own cases: k = 8, so blocks of 128 tokens and 1024 slots
+CK, CD, CR = 8, 128, 2048               # k, width, rows of y
+CBT = combine_tokens(CK)
+CBLOCKS = 3
+
+
+def _combine_rows(case):
+    """(N * k,) pair rows, -1 where a slot has no pair, for ``case``."""
+    rows = np.full((CBLOCKS, CBT * CK), -1)
+    sparse = lambda n: RNG.choice(CBT * CK, size=n, replace=False)
+    if case in ("empty_block", "accumulate"):        # block 1 holds none
+        for b in (0, 2):
+            rows[b, sparse(50)] = RNG.integers(CR, size=50)
+    elif case == "every_slot_live":                  # block 0 all live
+        rows[0] = RNG.integers(CR, size=CBT * CK)
+        rows[2, sparse(9)] = RNG.integers(CR, size=9)
+    elif case == "deeper_than_ring":                 # 2 rings and 3, 1 short
+        for b, n in enumerate((2 * grouped._RING + 3, grouped._RING - 1,
+                               grouped._RING)):
+            rows[b, sparse(n)] = RNG.integers(CR, size=n)
+    elif case == "all_sublane_offsets":
+        # token t's first pair reads a row at sublane t // 8 of its block:
+        # every (token, row) pair of sublanes, in blocks 0 and 2
+        for b in (0, 2):
+            for t in range(64):
+                rows[b, (t + 17) * CK + t % CK] = (
+                    8 * RNG.integers(CR // 8) + t // 8)
+    else:
+        raise ValueError(case)
+    return rows.reshape(-1)
+
+
+@pytest.mark.parametrize("case", ["empty_block", "every_slot_live",
+                                  "deeper_than_ring", "all_sublane_offsets",
+                                  "accumulate"])
+def test_combine_against_float64_oracle(case):
+    """Each token's output is its earlier output (zero in a first round)
+    plus its live pairs' ``w * y[row]``; a token with none keeps it
+    exactly."""
+    n = CBLOCKS * CBT
+    pair_row = _combine_rows(case)
+    w = RNG.uniform(0.1, 1.0, size=pair_row.size).astype(np.float32)
+    y = RNG.normal(size=(CR, CD)).astype(np.float32)
+    prev = (RNG.normal(size=(n, CD)).astype(np.float32)
+            if case == "accumulate" else np.zeros((n, CD), np.float32))
+    fn = grouped.make_combine(n, CD, CK, interpret=True)
+    args = [jnp.asarray(pair_row, jnp.int32), jnp.asarray(w), jnp.asarray(y)]
+    if case == "accumulate":
+        args.append(jnp.asarray(prev))
+    out = np.asarray(jax.jit(fn)(*args))
+
+    ref = prev.astype(np.float64)
+    size = np.abs(ref)
+    for slot in np.flatnonzero(pair_row >= 0):
+        term = np.float64(w[slot]) * y[pair_row[slot]].astype(np.float64)
+        ref[slot // CK] += term
+        size[slot // CK] += np.abs(term)
+    # float32 adds of at most k + 1 terms, each rounded once
+    assert np.all(np.abs(out - ref) <= (CK + 1) * np.finfo(np.float32).eps
+                  * size)
+    untouched = ~(pair_row >= 0).reshape(n, CK).any(axis=1)
+    assert untouched.any()
+    assert np.array_equal(out[untouched], prev[untouched])
+
+
+def _brute_pair_counts(pair_rows, k):
+    """Pairs walked, slots skipped and empty blocks, one slot at a time."""
+    per_block = {}
+    slots = combine_tokens(k) * k
+    for slot, row in enumerate(pair_rows):
+        per_block.setdefault(slot // slots, 0)
+        per_block[slot // slots] += row >= 0
+    walked = sum(per_block.values())
+    return (walked, len(per_block) * slots - walked,
+            sum(1 for n in per_block.values() if n == 0))
+
+
+@pytest.mark.parametrize("k,tokens,live", [
+    (8, 128, 0.0), (8, 384, 0.03), (8, 300, 0.5), (8, 256, 1.0),
+    (6, 700, 0.1), (4, 100, 0.2),
+])
+def test_combine_pair_counts_against_brute_force(k, tokens, live):
+    rows = np.where(RNG.uniform(size=tokens * k) < live,
+                    RNG.integers(1000, size=tokens * k), -1)
+    assert combine_pair_counts(rows, k) == _brute_pair_counts(rows, k)
 
 
 def test_tune_kernel_finds_and_verifies_a_winner(tmp_path):
