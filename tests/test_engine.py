@@ -63,11 +63,11 @@ class TableEvaluator(Evaluator):
 
 
 def run_engine(strategy, budget, *, fn=quadratic, space=None, seed=0,
-               **engine_kwargs):
+               seeds=None, **engine_kwargs):
     space = space or make_space()
     ev = TableEvaluator(fn)
     eng = EvaluationEngine(ev, SPEC, space, EngineConfig(**engine_kwargs))
-    res = eng.run(strategy, budget, seed=seed)
+    res = eng.run(strategy, budget, seed=seed, seeds=seeds)
     return res, eng, ev
 
 
@@ -140,6 +140,85 @@ def test_full_search_through_engine_is_exhaustive():
     assert res.evaluations == sp.size()
     assert ev.measure_calls == sp.size()
     assert res.best_time == 1.0
+
+
+#: warm-start seeds for the pinned trajectories: two usable configs, one
+#: with a value outside its parameter's list and a duplicate (both dropped)
+PINNED_SEEDS = [{"p0": 2, "p1": 2, "p2": 1, "p3": 0},
+                {"p0": 0, "p1": 3, "p2": 2, "p3": 2},
+                {"p0": 7, "p1": 0, "p2": 0, "p3": 0},
+                {"p0": 2, "p1": 2, "p2": 1, "p3": 0}]
+
+#: (strategy, seeded) -> (trial configs as p0p1p2p3 digits, pinned extras)
+#: of ``EvaluationEngine.run`` on make_space() with quadratic, budget 24,
+#: seed 11.  Greedy's restart after a cycle that spent the budget is a
+#: 25th trial.
+PINNED_TRAJECTORIES = {
+    ("full", False): (
+        "0000 0001 0002 0003 0010 0011 0012 0013 0020 0021 0022 0023 "
+        "0030 0031 0032 0033 0100 0101 0102 0103 0110 0111 0112 0113", {}),
+    ("full", True): (
+        "0000 0001 0002 0003 0010 0011 0012 0013 0020 0021 0022 0023 "
+        "0030 0031 0032 0033 0100 0101 0102 0103 0110 0111 0112 0113", {}),
+    ("random", False): (
+        "3331 1310 3210 0331 0000 1103 2311 2300 3230 2212 0003 0230 "
+        "0011 0333 3012 2022 0301 1000 3311 3113 3033 1022 0113 3202", {}),
+    ("random", True): (
+        "2210 0322 3331 1310 3210 0331 0000 1103 2311 2300 3230 2212 "
+        "0003 0230 0011 0333 3012 2022 0301 1000 3311 3113 3033 1022", {}),
+    ("annealing", False): (
+        "3331 2331 2311 3311 3111 3131 3133 3130 3113 3110 3111 3131 "
+        "0131 0031 0011 0010 0031 0231 0232 0231 0221 2221 2321 2231",
+        {"accepted_worse": 13, "temperature": 4.0}),
+    ("annealing", True): (
+        "2210 0322 2220 2210 2211 2231 2230 1230 3230 3233 3230 3213 "
+        "3210 3211 3231 0231 0031 0211 0210 0231 0131 0132 0131 0121",
+        {"accepted_worse": 13, "temperature": 4.0}),
+    ("pso", False): (
+        "3331 1310 3210 3331 3330 3011 3131 3330 3130 3101 1330 0322 "
+        "3311 3311 1331 2311 3101 2230 1233 3311 2111 3310 2331 2131",
+        {"swarm_size": 3, "particle_traces": [
+            [5.0, 5.0, 5.0, 8.0, 5.0, 4.0, 4.0, 8.0],
+            [8.0, 8.0, 8.0, 8.0, 5.0, 8.0, 5.0, 4.0],
+            [7.0, 8.0, 8.0, 6.0, 5.0, 6.0, 4.0, 4.0]]}),
+    ("pso", True): (
+        "2210 0322 3331 3332 0331 3030 1331 3332 3031 3031 0312 3002 "
+        "2232 1332 1312 3222 1231 0122 3012 3333 1121 1232 2033 3121",
+        {"swarm_size": 3, "particle_traces": [
+            [6.0, 4.0, 5.0, 8.0, 2.0, 2.0, 7.0, 3.0],
+            [6.0, 8.0, 4.0, 7.0, 4.0, 4.0, 5.0, 7.0],
+            [5.0, 11.0, 8.0, 10.0, 4.0, 6.0, 4.0, 4.0]]}),
+    ("greedy", False): (
+        "3331 0331 1331 2331 3331 2031 2131 2231 2331 2201 2211 2221 "
+        "2231 2220 2222 2223 0222 1222 3222 2022 2122 2322 2202 2212 "
+        "1310", {}),
+    ("greedy", True): (
+        "2210 0322 0210 1210 3210 2010 2110 2310 2200 2220 2230 2221 "
+        "2222 2223 0222 1222 3222 2022 2122 2322 2202 2212 2232 2220 "
+        "3331", {}),
+    ("evolutionary", False): (
+        "3331 1310 3210 0331 0000 1103 2311 2300 2210 3311 2311 3211 "
+        "2301 3333 2312 2311 2212 3312 1311 2312 0012 3311 2311 2211",
+        {"population": 8}),
+    ("evolutionary", True): (
+        "2210 0322 3331 1310 3210 0331 0000 1103 0312 0211 2231 0332 "
+        "3231 3330 3331 2231 2231 3331 2331 3231 3331 0031 2231 2231",
+        {"population": 8}),
+}
+
+
+@pytest.mark.parametrize("name,seeded", sorted(PINNED_TRAJECTORIES))
+def test_engine_trajectory_pinned(name, seeded):
+    """Every strategy's search, as the engine drives it, trial for trial."""
+    want_trials, want_extra = PINNED_TRAJECTORIES[name, seeded]
+    res, _, _ = run_engine(make_strategy(name), 24, seed=11,
+                           seeds=PINNED_SEEDS if seeded else None)
+    trials = " ".join("".join(str(t.config[f"p{i}"]) for i in range(4))
+                      for t in res.trials)
+    assert trials == want_trials
+    assert [t.time for t in res.trials] == [quadratic(t.config)
+                                            for t in res.trials]
+    assert {k: res.extra[k] for k in want_extra} == want_extra
 
 
 # -- early-stop pruning -------------------------------------------------------
