@@ -39,8 +39,8 @@ from .registry import (REGISTRY, AutotunePolicy, KernelRegistry, Resolution,
 from .space import Config, Constraint, Parameter, SearchSpace
 from .strategies import (AskTellDriver, Evolutionary, FullSearch,
                          GreedyCoordinateDescent, ParticleSwarm,
-                         RandomSearch, SearchResult, SequentialAskTell,
-                         SimulatedAnnealing, Strategy, Trial,
+                         RandomSearch, SearchResult, SimulatedAnnealing,
+                         Strategy, Trial,
                          available_strategies, make_strategy,
                          project_feasible, register_strategy, usable_seeds)
 from .tuner import Tuner, TuningOutcome
@@ -73,7 +73,7 @@ __all__ = [
     "Config", "Constraint", "Parameter", "SearchSpace",
     "AskTellDriver", "Evolutionary", "FullSearch",
     "GreedyCoordinateDescent", "ParticleSwarm", "RandomSearch",
-    "SearchResult", "SequentialAskTell", "SimulatedAnnealing",
+    "SearchResult", "SimulatedAnnealing",
     "Strategy", "Trial",
     "available_strategies", "make_strategy", "project_feasible",
     "register_strategy", "usable_seeds",

@@ -10,13 +10,13 @@ paper can explore.  This engine decouples the two halves of an evaluation:
   timing samples never contend with each other or with compilation of
   *other* candidates' artifacts only — never the measured one.
 
-Candidates arrive in batches through the strategies' ask/tell drivers
+Candidates arrive in batches through the strategy's ask/tell driver
 (:mod:`repro.core.strategies`): generation-based strategies (PSO,
 evolutionary, random, full) yield whole populations per ask, while
-inherently sequential walks (simulated annealing, greedy descent) run
-through a thread-bridged fallback one config per ask — optionally with
-*speculative* neighbour prefetch, which warms the compile pool with the
-configurations the walk is most likely to ask next.
+inherently sequential walks (simulated annealing, greedy descent) ask one
+config at a time — optionally with *speculative* neighbour prefetch, which
+warms the compile pool with the configurations the walk is most likely to
+ask next.
 
 Three further throughput levers:
 
@@ -65,7 +65,7 @@ from .failures import (CircuitBreakerTripped, CompileError, FailureRecord,
                        RetryPolicy, summarize_failures)
 from .metrics import Objective, default_objective
 from .space import Config, SearchSpace
-from .strategies import SearchResult, Strategy, Trial, accepts_kwarg
+from .strategies import SearchResult, Strategy, Trial
 
 log = logging.getLogger("repro.engine")
 
@@ -88,9 +88,6 @@ class EngineConfig:
     #: compile-pool width; 1 disables the pool (fully serial compiles);
     #: None = auto (min(4, cores - 2), clamped to >= 1)
     workers: Optional[int] = None
-    #: use the strategies' native batched drivers; False forces the
-    #: sequential fallback for every strategy (debug / equivalence runs)
-    batching: bool = True
     #: early-stop threshold factor k (prune once running median exceeds
     #: k × incumbent); None disables pruning
     prune_factor: Optional[float] = None
@@ -435,10 +432,9 @@ class EvaluationEngine:
                         aborted: Dict[str, Any]) -> SearchResult:
         """Synthesize a SearchResult from the evaluations already told.
 
-        The driver may be mid-generation (or, for the thread-bridged
-        sequential fallback, mid-``run``) when the breaker trips or a
-        stop is requested, so the engine's own tell-order history — not
-        the driver — is the source of truth for an aborted search.
+        The walk may be mid-batch when the breaker trips or a stop is
+        requested, so the engine's own tell-order history — not the
+        driver — is the source of truth for an aborted search.
         """
         trials = [Trial(config=c, time=t, index=i)
                   for i, (c, t) in enumerate(self._history)]
@@ -587,17 +583,7 @@ class EvaluationEngine:
         byte-identical to the pre-warm-start behaviour."""
         cfg = self.config
         t_run0 = time.perf_counter()
-        kwargs: Dict[str, Any] = {"seed": seed}
-        if cfg.batching:
-            # user strategies may override asktell with the pre-warm-start
-            # signature; their searches simply run cold
-            if seeds and accepts_kwarg(strategy.asktell, "seeds"):
-                kwargs["seeds"] = seeds
-            driver = strategy.asktell(self.space, budget, **kwargs)
-        else:   # force the sequential fallback regardless of strategy type
-            if seeds:
-                kwargs["seeds"] = seeds     # base asktell always takes them
-            driver = Strategy.asktell(strategy, self.space, budget, **kwargs)
+        driver = strategy.asktell(self.space, budget, seed=seed, seeds=seeds)
         pool = (ThreadPoolExecutor(max_workers=cfg.workers,
                                    thread_name_prefix="engine-compile")
                 if cfg.workers > 1 else None)
@@ -673,7 +659,7 @@ class EvaluationEngine:
                                        "max_failures": t.limit}
                             self.stats.aborted = True
                             break
-                # a partial tell (breaker mid-batch) is fine: every driver
+                # a partial tell (breaker mid-batch) is fine: the driver
                 # accepts fewer results than it asked for
                 if results:
                     with jax.profiler.TraceAnnotation("repro.engine.strategy"):

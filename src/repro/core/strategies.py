@@ -6,6 +6,11 @@ methods, stochastic optimisation or dynamic programming can be evaluated as
 part of future work" (paper, end of III-B).  We add one beyond-paper strategy
 (greedy coordinate descent) used by the sharding tuner.
 
+Each strategy holds its search once, as a *walk*: a generator that yields
+batches of configurations and is sent their objective values.  The
+evaluation engine drives every walk through one :class:`AskTellDriver`, and
+``Strategy.run`` drives the same walk against a plain objective function.
+
 Objective convention: *lower is better* (execution time in seconds), exactly
 like the paper's annealing-energy analogy.  Infeasible / failed measurements
 return ``math.inf`` and are recorded but never become the incumbent.
@@ -16,17 +21,13 @@ from __future__ import annotations
 import dataclasses
 import inspect
 import itertools
-import logging
 import math
-import queue
 import random
-import threading
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, Generator, List, Optional, Sequence,
+                    Tuple)
 
 from .failures import FailureRecord, summarize_failures
 from .space import Config, SearchSpace
-
-log = logging.getLogger("repro.strategies")
 
 #: scalar objective function over one config — lower is better.  Renamed
 #: from ``Objective``: the *typed* objective identity (median/p99/weighted
@@ -37,7 +38,7 @@ ObjectiveFn = Callable[[Config], float]
 
 def accepts_kwarg(fn: Callable, kwarg: str) -> bool:
     """Whether ``fn`` can take ``kwarg`` — shared signature introspection
-    for optional-capability probes (seeds support, extended spaces, ...)."""
+    for optional-capability probes (extended spaces, interpret mode)."""
     try:
         params = inspect.signature(fn).parameters
     except (TypeError, ValueError):    # builtins / C callables
@@ -197,72 +198,137 @@ class SearchResult:
         return summary
 
 
-class _Recorder:
-    """Shared bookkeeping: measurement cache, trial log, incumbent.
+#: one search as the engine drives it: a generator that yields each batch
+#: of configs to measure, is sent that batch's objective values in the
+#: order it yielded them, and returns the strategy's ``extra`` dict
+Walk = Generator[List[Config], List[float], Optional[Dict[str, object]]]
 
-    Re-visiting an already-measured configuration does NOT re-measure it
-    (CLTune's compiled-kernel cache) but DOES consume search budget — a
-    stochastic walk that keeps revisiting known points must still
-    terminate.  ``unique_evaluations`` reports how many distinct configs
-    were actually measured.
-    """
 
-    def __init__(self, space: SearchSpace, objective: ObjectiveFn):
-        self._space = space
-        self._objective = objective
-        self._seen: Dict[Tuple, float] = {}
-        self.trials: List[Trial] = []
-        self.best: Optional[Trial] = None
-
-    def evaluate(self, config: Config) -> float:
-        key = self._space.config_key(config)
-        if key in self._seen:
-            t = self._seen[key]          # cached measurement
-        else:
-            t = float(self._objective(config))
-            self._seen[key] = t
-        trial = Trial(config=dict(config), time=t, index=len(self.trials))
-        self.trials.append(trial)
-        if math.isfinite(t) and (self.best is None or t < self.best.time):
-            self.best = trial
-        return t
-
-    @property
-    def evaluations(self) -> int:
-        return len(self.trials)
-
-    @property
-    def unique_evaluations(self) -> int:
-        return len(self._seen)
+def _require_budget(strategy: "Strategy", budget: Optional[int]) -> int:
+    """Only full search supports budget=None (exhaustive enumeration)."""
+    if budget is None:
+        raise ValueError(f"strategy {strategy.name!r} requires a finite "
+                         "budget (budget=None is full-search only)")
+    return budget
 
 
 class Strategy:
-    """Base class; subclasses implement ``run``.
+    """Base class; a subclass implements ``walk``, its whole search.
 
-    ``run``/``asktell`` accept optional warm-start ``seeds``: sanitized
-    initial candidates (transferred nearest-shape winners, heuristics)
-    evaluated before — or, for population strategies, as part of — the
-    strategy's own exploration.  Seeds consume search budget like any
-    other evaluation.
+    ``walk(space, budget, seed, seeds)`` is a generator (:data:`Walk`):
+    ``times = yield batch`` asks for one non-empty batch of configs, and
+    the walk returns its ``extra`` dict when it is done.  Sequential walks
+    (annealing, greedy) ask one config at a time, generation-based ones
+    (PSO, evolutionary) a whole population.  Every evaluation, a revisit
+    included, counts against ``budget``.
 
-    ``asktell`` is the batch interface consumed by
-    :class:`repro.core.engine.EvaluationEngine`: generation-based
-    strategies override it with native batched drivers, everything else
-    inherits a sequential fallback that wraps ``run`` unchanged
-    (forwarding ``seeds`` when the strategy's ``run`` accepts them).
+    ``seeds`` are optional warm-start candidates: sanitized initial configs
+    (transferred nearest-shape winners, heuristics) evaluated before — or,
+    for population strategies, as part of — the strategy's own
+    exploration.  Seeds consume search budget like any other evaluation.
+
+    :class:`repro.core.engine.EvaluationEngine` drives the walk through
+    :meth:`asktell`; :meth:`run` drives the same walk against a plain
+    objective function.
     """
 
     name = "base"
 
-    def run(self, space: SearchSpace, objective: ObjectiveFn,
-            budget: int, seed: int = 0,
-            seeds: Optional[Sequence[Config]] = None) -> SearchResult:
+    def walk(self, space: SearchSpace, budget: Optional[int], seed: int = 0,
+             seeds: Optional[Sequence[Config]] = None) -> Walk:
         raise NotImplementedError
 
     def asktell(self, space: SearchSpace, budget: Optional[int],
                 seed: int = 0,
                 seeds: Optional[Sequence[Config]] = None) -> "AskTellDriver":
-        return SequentialAskTell(self, space, budget, seed=seed, seeds=seeds)
+        return AskTellDriver(self, space,
+                             self.walk(space, budget, seed=seed, seeds=seeds))
+
+    def run(self, space: SearchSpace, objective: ObjectiveFn,
+            budget: Optional[int], seed: int = 0,
+            seeds: Optional[Sequence[Config]] = None) -> SearchResult:
+        """Search against ``objective`` directly.  A revisited config is
+        answered from this run's memo, not measured again (CLTune's
+        compiled-kernel cache), but still counts against ``budget``."""
+        driver = self.asktell(space, budget, seed=seed, seeds=seeds)
+        memo: Dict[Tuple, float] = {}
+        while batch := driver.ask():
+            keys = [space.config_key(cfg) for cfg in batch]
+            for cfg, key in zip(batch, keys):
+                if key not in memo:
+                    memo[key] = float(objective(cfg))
+            driver.tell([(cfg, memo[key]) for cfg, key in zip(batch, keys)])
+        return driver.result()
+
+
+class AskTellDriver:
+    """One search run with control inverted: the caller pulls batches.
+
+    ``ask()`` returns the walk's next batch, or ``[]`` once the walk has
+    returned.  The caller evaluates the batch however it likes — parallel
+    compilation, memoisation, early-stop pruning, in any order — and
+    reports objective values with ``tell()``, which records the trials in
+    the order told.  At the next ``ask()`` the walk gets the batch's values
+    in the order it asked for them, matched by config identity, so the
+    whole batch must be told by then; a partial tell before an aborted
+    search is fine.  ``result()`` is valid once ``ask()`` has returned
+    ``[]``.
+    """
+
+    def __init__(self, strategy: Strategy, space: SearchSpace, walk: Walk):
+        self.strategy = strategy
+        self._space = space
+        self._walk = walk
+        self._trials: List[Trial] = []
+        self._best: Optional[Trial] = None
+        self._told: Dict[Tuple, float] = {}
+        self._asked = False
+        self._extra: Optional[Dict[str, object]] = None
+        self._step(None)        # to the first batch: bad arguments raise here
+
+    def _step(self, times: Optional[List[float]]) -> None:
+        try:
+            self._batch = list(self._walk.send(times))
+        except StopIteration as done:
+            self._batch = []
+            self._extra = dict(done.value or {})
+
+    def ask(self) -> List[Config]:
+        if self._asked and self._batch:
+            try:
+                times = [self._told[self._space.config_key(c)]
+                         for c in self._batch]
+            except KeyError:
+                raise RuntimeError("ask() before every config of the last "
+                                   "batch was told") from None
+            self._step(times)
+        self._asked, self._told = True, {}
+        return [dict(c) for c in self._batch]
+
+    def tell(self, results: List[Tuple[Config, float]]) -> None:
+        for config, time_s in results:
+            trial = Trial(config=dict(config), time=float(time_s),
+                          index=len(self._trials))
+            self._trials.append(trial)
+            if trial.ok and (self._best is None
+                             or trial.time < self._best.time):
+                self._best = trial
+            self._told[self._space.config_key(config)] = trial.time
+
+    def result(self) -> SearchResult:
+        if self._extra is None:
+            raise RuntimeError(
+                "result() before the search finished; a caller that aborts "
+                "a search assembles its partial result itself (the "
+                "EvaluationEngine does, from its tell history)")
+        return SearchResult(self.strategy.name, list(self._trials),
+                            self._best, len(self._trials),
+                            extra=dict(self._extra))
+
+    def close(self) -> None:
+        """Stop the walk where it stands (idempotent)."""
+        self._walk.close()
+        self._batch = []
 
 
 class FullSearch(Strategy):
@@ -288,52 +354,37 @@ class FullSearch(Strategy):
         self.offset = offset
         self.stride = stride
 
-    def _configs(self, space: SearchSpace):
-        return itertools.islice(iter(space), self.offset, None, self.stride)
-
-    def run(self, space, objective, budget=None, seed=0,
-            seeds=None) -> SearchResult:
-        rec = _Recorder(space, objective)
-        for i, cfg in enumerate(self._configs(space)):
-            if budget is not None and i >= budget:
-                break
-            rec.evaluate(cfg)
-        return SearchResult(self.name, rec.trials, rec.best, rec.evaluations)
-
-    def asktell(self, space, budget, seed=0, seeds=None) -> "AskTellDriver":
-        return _FullSearchAskTell(self, space, budget)
+    def walk(self, space, budget=None, seed=0, seeds=None) -> Walk:
+        configs = itertools.islice(iter(space), self.offset, None,
+                                   self.stride)
+        if budget is not None:
+            configs = itertools.islice(configs, budget)
+        while batch := list(itertools.islice(configs, 64)):  # engine-sized
+            yield batch
 
 
 class RandomSearch(Strategy):
     """Uniform sampling of a configurable fraction of the space.
 
-    Warm-start seeds are evaluated first and count toward the budget; the
-    random sample fills the remainder (seeds excluded from re-draws).
+    The whole sample is one batch, maximally overlappable.  Warm-start
+    seeds lead it and count toward the budget; random draws fill the
+    remainder (seeds excluded from re-draws).
     """
 
     name = "random"
 
-    def run(self, space, objective, budget, seed=0,
-            seeds=None) -> SearchResult:
-        rng = random.Random(seed)
-        rec = _Recorder(space, objective)
-        seeds = usable_seeds(space, seeds, limit=budget)
-        for cfg in seeds:
-            rec.evaluate(cfg)
-        samples = _sample_avoiding(space, rng, budget - len(seeds), seeds)
-        for cfg in samples:
-            rec.evaluate(cfg)
-        extra: Dict[str, object] = {}
-        if rec.evaluations < budget:
+    def walk(self, space, budget, seed=0, seeds=None) -> Walk:
+        budget = _require_budget(self, budget)
+        planted = usable_seeds(space, seeds, limit=budget)
+        batch = planted + _sample_avoiding(
+            space, random.Random(seed), budget - len(planted), planted)
+        if batch:
+            yield batch
+        if len(batch) < budget:
             # the feasible space is smaller than the budget: surface the
             # shortfall instead of silently under-spending
-            extra["sample_shortfall"] = budget - rec.evaluations
-        return SearchResult(self.name, rec.trials, rec.best, rec.evaluations,
-                            extra=extra)
-
-    def asktell(self, space, budget, seed=0, seeds=None) -> "AskTellDriver":
-        return _RandomSearchAskTell(self, space, budget, seed=seed,
-                                    seeds=seeds)
+            return {"sample_shortfall": budget - len(batch)}
+        return {}
 
 
 class SimulatedAnnealing(Strategy):
@@ -345,7 +396,8 @@ class SimulatedAnnealing(Strategy):
     with T the annealing temperature and t, t' the execution times of the
     current and neighbour configuration.  As in CLTune the walk starts from a
     random feasible configuration and runs until ``budget`` configurations
-    have been explored.  ``temperature`` is expressed in the objective's
+    have been explored; a configuration with no feasible neighbour restarts
+    it from a random one.  ``temperature`` is expressed in the objective's
     units scaled by the first measurement, so T={2,4,6} behaves like the
     paper's settings regardless of kernel magnitude; ``cooling`` optionally
     anneals T linearly to ~0 over the run ("probability decreases over time
@@ -354,52 +406,49 @@ class SimulatedAnnealing(Strategy):
 
     name = "annealing"
 
-    def __init__(self, temperature: float = 4.0, cooling: bool = True,
-                 neighbour_mode: str = "any_value",
-                 restart_on_dead_end: bool = True):
+    def __init__(self, temperature: float = 4.0, cooling: bool = True):
         self.temperature = float(temperature)
         self.cooling = cooling
-        self.neighbour_mode = neighbour_mode
-        self.restart_on_dead_end = restart_on_dead_end
 
-    def run(self, space, objective, budget, seed=0,
-            seeds=None) -> SearchResult:
+    def walk(self, space, budget, seed=0, seeds=None) -> Walk:
+        budget = _require_budget(self, budget)
         rng = random.Random(seed)
-        rec = _Recorder(space, objective)
         # Warm start: evaluate every seed, then walk from the best of them
         # (transferred nearest-shape winners put the walk straight into a
-        # good basin).  Without seeds the walk starts at a random sample,
-        # exactly as before.
+        # good basin).  Without seeds the walk starts at a random sample.
+        times: List[float] = []
         current, t_cur = None, math.inf
         for cfg in usable_seeds(space, seeds, limit=budget):
-            t = rec.evaluate(cfg)
+            t, = yield [cfg]
+            times.append(t)
             if current is None or t < t_cur:
                 current, t_cur = cfg, t
         if current is None:
             current = space.sample(rng)
-            t_cur = rec.evaluate(current)
+            t_cur, = yield [current]
+            times.append(t_cur)
         # Temperature scale: the first *finite* measurement, refreshed on
         # dead-end restarts.  Seeding it from an inf (failed) first eval —
         # or keeping a stale basin's scale after a restart — mis-sizes
         # every subsequent acceptance probability.
-        scale = next((t.time for t in rec.trials
-                      if math.isfinite(t.time) and t.time > 0), None)
+        scale = next((t for t in times if math.isfinite(t) and t > 0), None)
+        done = len(times)
         accepted_worse = 0
-        while rec.evaluations < budget:
-            nbr = space.random_neighbour(current, rng, mode=self.neighbour_mode)
+        while done < budget:
+            nbr = space.random_neighbour(current, rng)
             if nbr is None:
-                if not self.restart_on_dead_end:
-                    break
                 current = space.sample(rng)
-                t_cur = rec.evaluate(current)
+                t_cur, = yield [current]
+                done += 1
                 if math.isfinite(t_cur) and t_cur > 0:
                     scale = t_cur           # recalibrate to the new basin
                 continue
-            t_nbr = rec.evaluate(nbr)
+            t_nbr, = yield [nbr]
+            done += 1
             if scale is None and math.isfinite(t_nbr) and t_nbr > 0:
                 scale = t_nbr               # first finite measurement seen
             # temperature in units of the scale measurement; linear cooling
-            frac_done = rec.evaluations / max(budget, 1)
+            frac_done = done / max(budget, 1)
             T = self.temperature * (1.0 - frac_done if self.cooling else 1.0)
             T = max(T, 1e-9)
             if t_nbr < t_cur:
@@ -412,9 +461,8 @@ class SimulatedAnnealing(Strategy):
                 if t_nbr >= t_cur:
                     accepted_worse += 1
                 current, t_cur = nbr, t_nbr
-        return SearchResult(self.name, rec.trials, rec.best, rec.evaluations,
-                            extra={"accepted_worse": accepted_worse,
-                                   "temperature": self.temperature})
+        return {"accepted_worse": accepted_worse,
+                "temperature": self.temperature}
 
 
 class ParticleSwarm(Strategy):
@@ -428,7 +476,9 @@ class ParticleSwarm(Strategy):
                   x[i,d]     otherwise                (stay)
 
     with alpha + beta + gamma <= 1.  Paper experiments use alpha=0.4, beta=0,
-    gamma=0.4, swarm sizes S in {3, 6}.
+    gamma=0.4, swarm sizes S in {3, 6}.  Generation-synchronous: each batch
+    is the whole swarm, and every particle of a generation moves against
+    the global best of the generation before.
     """
 
     name = "pso"
@@ -462,39 +512,34 @@ class ParticleSwarm(Strategy):
                 return new
         return space.sample(rng)    # repair failed: rerandomise the particle
 
-    def run(self, space, objective, budget, seed=0,
-            seeds=None) -> SearchResult:
+    def walk(self, space, budget, seed=0, seeds=None) -> Walk:
+        budget = _require_budget(self, budget)
         rng = random.Random(seed)
-        rec = _Recorder(space, objective)
         n = self.swarm_size
         # Warm start: the first particles spawn at the seed configs, the
         # rest randomly — the swarm explores around transferred winners.
         planted = usable_seeds(space, seeds, limit=n)
         xs = planted + [space.sample(rng) for _ in range(n - len(planted))]
-        ts = [rec.evaluate(x) for x in xs]
-        p_best = list(xs)
-        p_time = list(ts)
-        g_i = min(range(n), key=lambda i: p_time[i])
-        g_best, g_time = dict(p_best[g_i]), p_time[g_i]
-        particle_traces: List[List[float]] = [[t] for t in ts]
-        while rec.evaluations < budget:
-            for i in range(n):
-                if rec.evaluations >= budget:
-                    break
-                xs[i] = self._move(space, rng, xs[i], p_best[i], g_best)
-                ts[i] = rec.evaluate(xs[i])
-                particle_traces[i].append(ts[i])
-                if ts[i] < p_time[i]:
-                    p_best[i], p_time[i] = dict(xs[i]), ts[i]
-                if ts[i] < g_time:
-                    g_best, g_time = dict(xs[i]), ts[i]
-        return SearchResult(self.name, rec.trials, rec.best, rec.evaluations,
-                            extra={"particle_traces": particle_traces,
-                                   "swarm_size": n})
-
-    def asktell(self, space, budget, seed=0, seeds=None) -> "AskTellDriver":
-        return _ParticleSwarmAskTell(self, space, budget, seed=seed,
-                                     seeds=seeds)
+        p_best = [dict(x) for x in xs]
+        p_time = [math.inf] * n
+        g_best: Optional[Config] = None
+        g_time = math.inf
+        traces: List[List[float]] = [[] for _ in range(n)]
+        done = 0
+        while (k := min(budget - done, n)) > 0:
+            times = yield xs[:k]
+            done += k
+            for i, t in enumerate(times):
+                traces[i].append(t)
+                if t < p_time[i]:
+                    p_best[i], p_time[i] = dict(xs[i]), t
+                if t < g_time:
+                    g_best, g_time = dict(xs[i]), t
+            if done < budget:
+                g = g_best if g_best is not None else xs[0]
+                xs = [self._move(space, rng, x, p, g)
+                      for x, p in zip(xs, p_best)]
+        return {"particle_traces": traces, "swarm_size": n}
 
 
 class GreedyCoordinateDescent(Strategy):
@@ -506,23 +551,25 @@ class GreedyCoordinateDescent(Strategy):
 
     name = "greedy"
 
-    def run(self, space, objective, budget, seed=0,
-            seeds=None) -> SearchResult:
+    def walk(self, space, budget, seed=0, seeds=None) -> Walk:
+        budget = _require_budget(self, budget)
         rng = random.Random(seed)
-        rec = _Recorder(space, objective)
+        done = 0
         # Warm start: descend from the best seed instead of a random point
         current, t_cur = None, math.inf
         for cfg in usable_seeds(space, seeds, limit=budget):
-            t = rec.evaluate(cfg)
+            t, = yield [cfg]
+            done += 1
             if current is None or t < t_cur:
                 current, t_cur = cfg, t
         if current is None:
             current = space.sample(rng)
-            t_cur = rec.evaluate(current)
-        while rec.evaluations < budget:
+            t_cur, = yield [current]
+            done += 1
+        while done < budget:
             improved = False
             for param in space.parameters:
-                if rec.evaluations >= budget:
+                if done >= budget:
                     break
                 for v in param.values:
                     if v == current[param.name]:
@@ -531,16 +578,18 @@ class GreedyCoordinateDescent(Strategy):
                     cand[param.name] = v
                     if not space.is_feasible(cand):
                         continue
-                    t = rec.evaluate(cand)
+                    t, = yield [cand]
+                    done += 1
                     if t < t_cur:
                         current, t_cur = cand, t
                         improved = True
-                    if rec.evaluations >= budget:
+                    if done >= budget:
                         break
             if not improved:
                 current = space.sample(rng)      # random restart
-                t_cur = rec.evaluate(current)
-        return SearchResult(self.name, rec.trials, rec.best, rec.evaluations)
+                t_cur, = yield [current]
+                done += 1
+        return {}
 
 
 class Evolutionary(Strategy):
@@ -548,7 +597,8 @@ class Evolutionary(Strategy):
 
     Tournament selection, uniform crossover per dimension, per-dimension
     mutation to a random value; elitism keeps the incumbent.  Infeasible
-    offspring are repaired by re-sampling.
+    offspring are repaired by re-sampling.  Each batch is one generation's
+    offspring.
     """
 
     name = "evolutionary"
@@ -573,16 +623,18 @@ class Evolutionary(Strategy):
                 return child
         return space.sample(rng)
 
-    def run(self, space, objective, budget, seed=0,
-            seeds=None) -> SearchResult:
+    def walk(self, space, budget, seed=0, seeds=None) -> Walk:
+        budget = _require_budget(self, budget)
         rng = random.Random(seed)
-        rec = _Recorder(space, objective)
         # Warm start: seeds join generation 0 (elitism then carries the
         # best transferred config forward until something beats it)
         planted = usable_seeds(space, seeds, limit=self.population)
-        pop = planted + [space.sample(rng)
-                         for _ in range(self.population - len(planted))]
-        fit = [rec.evaluate(x) for x in pop]
+        batch = planted + [space.sample(rng)
+                           for _ in range(self.population - len(planted))]
+        pop: List[Config] = []
+        fit: List[float] = []
+        elite: List[Config] = []
+        elite_fit: List[float] = []
 
         def tourney() -> Config:
             idx = min(rng.sample(range(len(pop)),
@@ -590,376 +642,19 @@ class Evolutionary(Strategy):
                       key=lambda i: fit[i])
             return pop[idx]
 
-        while rec.evaluations < budget:
-            elite_i = min(range(len(pop)), key=lambda i: fit[i])
-            new_pop = [pop[elite_i]]
-            new_fit = [fit[elite_i]]
-            while len(new_pop) < self.population \
-                    and rec.evaluations < budget:
-                child = self._offspring(space, rng, tourney(), tourney())
-                new_pop.append(child)
-                new_fit.append(rec.evaluate(child))
-            pop, fit = new_pop, new_fit
-        return SearchResult(self.name, rec.trials, rec.best,
-                            rec.evaluations,
-                            extra={"population": self.population})
-
-    def asktell(self, space, budget, seed=0, seeds=None) -> "AskTellDriver":
-        return _EvolutionaryAskTell(self, space, budget, seed=seed,
-                                    seeds=seeds)
-
-
-# ---------------------------------------------------------------------------
-# Batch ask/tell drivers — the EvaluationEngine's view of a strategy
-# ---------------------------------------------------------------------------
-
-class AskTellDriver:
-    """Inverted-control interface over one search run.
-
-    The evaluation engine pulls *batches* of candidate configurations with
-    ``ask()`` (an empty batch means the search finished), evaluates them
-    however it likes — parallel compilation, memoisation, early-stop
-    pruning — and reports objective values back with ``tell()``.
-    ``result()`` is valid once ``ask()`` has returned an empty batch.
-
-    Generation-based strategies (full, random, PSO, evolutionary) provide
-    native drivers whose batches are whole populations; every other
-    strategy inherits :class:`SequentialAskTell`, which runs the
-    strategy's own ``run`` loop unchanged and surfaces its objective
-    calls one configuration at a time.
-    """
-
-    strategy: Strategy
-
-    def ask(self) -> List[Config]:
-        raise NotImplementedError
-
-    def tell(self, results: List[Tuple[Config, float]]) -> None:
-        raise NotImplementedError
-
-    def result(self) -> SearchResult:
-        raise NotImplementedError
-
-    def close(self) -> None:
-        """Release resources (idempotent; safe after an aborted search)."""
-
-
-class SequentialAskTell(AskTellDriver):
-    """Bridge ``strategy.run`` into ask/tell via a worker thread.
-
-    The compatibility path: any Strategy subclass — including
-    user-registered ones that only implement ``run`` — works with the
-    engine, one configuration per batch, with trial-for-trial identical
-    results to a direct ``run()`` call (the strategy's own code runs,
-    its objective calls are simply answered from the engine).
-    """
-
-    def __init__(self, strategy: Strategy, space: SearchSpace,
-                 budget: Optional[int], seed: int = 0,
-                 seeds: Optional[Sequence[Config]] = None):
-        self.strategy = strategy
-        self._requests: "queue.Queue[Optional[Config]]" = queue.Queue(1)
-        self._responses: "queue.Queue[float]" = queue.Queue(1)
-        self._result: Optional[SearchResult] = None
-        self._error: Optional[BaseException] = None
-        self._finished = False
-        self._awaiting_tell = False
-        self._aborted = False
-        run_kwargs: Dict[str, Any] = {"seed": seed}
-        if seeds:
-            # inject warm-start seeds into strategies whose run() takes
-            # them (annealing, greedy, any compliant user strategy); a
-            # legacy run() signature just searches cold
-            if accepts_kwarg(strategy.run, "seeds"):
-                run_kwargs["seeds"] = [dict(c) for c in seeds]
-            else:
-                log.debug("strategy %r ignores warm-start seeds",
-                          strategy.name)
-
-        def _objective(config: Config) -> float:
-            self._requests.put(dict(config))
-            return self._responses.get()
-
-        def _run() -> None:
-            try:
-                self._result = strategy.run(space, _objective, budget,
-                                            **run_kwargs)
-            except BaseException as e:  # noqa: BLE001 — surfaced on next ask
-                self._error = e
-            finally:
-                self._requests.put(None)        # sentinel: run() returned
-
-        self._thread = threading.Thread(
-            target=_run, name=f"asktell-{strategy.name}", daemon=True)
-        self._thread.start()
-
-    def ask(self) -> List[Config]:
-        if self._finished:
-            return []
-        if self._awaiting_tell:
-            raise RuntimeError("ask() called with a tell() still pending")
-        config = self._requests.get()
-        if config is None:
-            self._finished = True
-            self._thread.join()
-            if self._error is not None:
-                raise self._error
-            return []
-        self._awaiting_tell = True
-        return [config]
-
-    def tell(self, results: List[Tuple[Config, float]]) -> None:
-        if not self._awaiting_tell:
-            raise RuntimeError("tell() without a pending ask()")
-        (_, time_s), = results
-        self._awaiting_tell = False
-        self._responses.put(float(time_s))
-
-    def result(self) -> SearchResult:
-        if self._aborted:
-            raise RuntimeError(
-                "result() unavailable: the driver was closed before the "
-                "search finished, so the strategy's own result would be a "
-                "drained partial run; the caller aborting the search is "
-                "responsible for assembling a partial result (the "
-                "EvaluationEngine synthesizes one from its tell history)")
-        if not self._finished or self._result is None:
-            raise RuntimeError("result() before the search finished")
-        return self._result
-
-    def close(self) -> None:
-        # Unblock an abandoned strategy thread (engine aborted mid-search):
-        # answer every outstanding objective call with inf until run()
-        # returns, then join the worker thread.  Bounded because every
-        # strategy is budget-bounded.
-        if not self._finished:
-            self._aborted = True
-        while not self._finished:
-            if self._awaiting_tell:
-                self._awaiting_tell = False
-                self._responses.put(math.inf)
-            nxt = self._requests.get()
-            if nxt is None:
-                self._finished = True
-            else:
-                self._awaiting_tell = True
-        self._thread.join()
-
-
-class _BatchRecorder:
-    """Trial log + incumbent for native batched drivers."""
-
-    def __init__(self):
-        self.trials: List[Trial] = []
-        self.best: Optional[Trial] = None
-
-    def add(self, config: Config, time_s: float) -> None:
-        trial = Trial(config=dict(config), time=float(time_s),
-                      index=len(self.trials))
-        self.trials.append(trial)
-        if trial.ok and (self.best is None or trial.time < self.best.time):
-            self.best = trial
-
-    @property
-    def evaluations(self) -> int:
-        return len(self.trials)
-
-
-class _FullSearchAskTell(AskTellDriver):
-    """Exhaustive enumeration in engine-sized chunks."""
-
-    def __init__(self, strategy: FullSearch, space: SearchSpace,
-                 budget: Optional[int], chunk: int = 64):
-        self.strategy = strategy
-        self._iter = strategy._configs(space)
-        self._budget = math.inf if budget is None else budget
-        self._chunk = chunk
-        self._rec = _BatchRecorder()
-        self._asked = 0
-
-    def ask(self) -> List[Config]:
-        limit = int(min(self._chunk, self._budget - self._asked))
-        batch: List[Config] = []
-        while len(batch) < limit:
-            try:
-                batch.append(next(self._iter))
-            except StopIteration:
+        done = 0
+        while batch and done < budget:
+            batch = batch[:budget - done]
+            times = yield batch
+            done += len(batch)
+            pop, fit = elite + batch, elite_fit + list(times)
+            if done >= budget:
                 break
-        self._asked += len(batch)
-        return batch
-
-    def tell(self, results: List[Tuple[Config, float]]) -> None:
-        for cfg, t in results:
-            self._rec.add(cfg, t)
-
-    def result(self) -> SearchResult:
-        return SearchResult(self.strategy.name, self._rec.trials,
-                            self._rec.best, self._rec.evaluations)
-
-
-def _require_budget(strategy: Strategy, budget: Optional[int]) -> int:
-    """Only full search supports budget=None (exhaustive enumeration)."""
-    if budget is None:
-        raise ValueError(f"strategy {strategy.name!r} requires a finite "
-                         "budget (budget=None is full-search only)")
-    return budget
-
-
-class _RandomSearchAskTell(AskTellDriver):
-    """The whole random sample is one batch — maximally overlappable.
-
-    Warm-start seeds lead the batch; random draws fill the remainder.
-    """
-
-    def __init__(self, strategy: RandomSearch, space: SearchSpace,
-                 budget: int, seed: int = 0,
-                 seeds: Optional[Sequence[Config]] = None):
-        budget = _require_budget(strategy, budget)
-        self.strategy = strategy
-        rng = random.Random(seed)
-        planted = usable_seeds(space, seeds, limit=budget)
-        self._pending: List[Config] = planted + _sample_avoiding(
-            space, rng, budget - len(planted), planted)
-        self._shortfall = budget - len(self._pending)
-        self._rec = _BatchRecorder()
-
-    def ask(self) -> List[Config]:
-        batch, self._pending = self._pending, []
-        return batch
-
-    def tell(self, results: List[Tuple[Config, float]]) -> None:
-        for cfg, t in results:
-            self._rec.add(cfg, t)
-
-    def result(self) -> SearchResult:
-        extra: Dict[str, object] = {}
-        if self._shortfall > 0:
-            extra["sample_shortfall"] = self._shortfall
-        return SearchResult(self.strategy.name, self._rec.trials,
-                            self._rec.best, self._rec.evaluations,
-                            extra=extra)
-
-
-class _ParticleSwarmAskTell(AskTellDriver):
-    """Generation-synchronous PSO: each batch is the whole swarm.
-
-    Within a generation every particle moves against the generation-start
-    global best (classic synchronous PSO), whereas ``ParticleSwarm.run``
-    refreshes the global best particle-by-particle; the two trajectories
-    coincide whenever no particle improves the incumbent mid-round.
-    """
-
-    def __init__(self, strategy: ParticleSwarm, space: SearchSpace,
-                 budget: int, seed: int = 0,
-                 seeds: Optional[Sequence[Config]] = None):
-        self.strategy = strategy
-        self.space = space
-        self.rng = random.Random(seed)
-        self._budget = _require_budget(strategy, budget)
-        self._rec = _BatchRecorder()
-        n = strategy.swarm_size
-        planted = usable_seeds(space, seeds, limit=n)
-        self.xs = planted + [space.sample(self.rng)
-                             for _ in range(n - len(planted))]
-        self.p_best = [dict(x) for x in self.xs]
-        self.p_time = [math.inf] * n
-        self.g_best: Optional[Config] = None
-        self.g_time = math.inf
-        self.traces: List[List[float]] = [[] for _ in range(n)]
-        self._moved_once = False
-        self._asked_idx: List[int] = []
-
-    def ask(self) -> List[Config]:
-        remaining = self._budget - self._rec.evaluations
-        if remaining <= 0:
-            return []
-        if self._moved_once:
-            g = self.g_best if self.g_best is not None else self.xs[0]
-            for i in range(len(self.xs)):
-                self.xs[i] = self.strategy._move(
-                    self.space, self.rng, self.xs[i], self.p_best[i], g)
-        self._moved_once = True
-        self._asked_idx = list(range(int(min(remaining, len(self.xs)))))
-        return [dict(self.xs[i]) for i in self._asked_idx]
-
-    def tell(self, results: List[Tuple[Config, float]]) -> None:
-        for i, (cfg, t) in zip(self._asked_idx, results):
-            t = float(t)
-            self._rec.add(cfg, t)
-            self.traces[i].append(t)
-            if t < self.p_time[i]:
-                self.p_best[i], self.p_time[i] = dict(cfg), t
-            if t < self.g_time:
-                self.g_best, self.g_time = dict(cfg), t
-
-    def result(self) -> SearchResult:
-        return SearchResult(self.strategy.name, self._rec.trials,
-                            self._rec.best, self._rec.evaluations,
-                            extra={"particle_traces": self.traces,
-                                   "swarm_size": self.strategy.swarm_size,
-                                   "synchronous": True})
-
-
-class _EvolutionaryAskTell(AskTellDriver):
-    """Generation-batched GA: ask yields the next population's offspring."""
-
-    def __init__(self, strategy: Evolutionary, space: SearchSpace,
-                 budget: int, seed: int = 0,
-                 seeds: Optional[Sequence[Config]] = None):
-        self.strategy = strategy
-        self.space = space
-        self.rng = random.Random(seed)
-        self._budget = _require_budget(strategy, budget)
-        self._rec = _BatchRecorder()
-        self.pop: List[Config] = []
-        self.fit: List[float] = []
-        planted = usable_seeds(space, seeds, limit=strategy.population)
-        self._initial = planted + [
-            space.sample(self.rng)
-            for _ in range(strategy.population - len(planted))]
-        self._elite: Optional[Tuple[Config, float]] = None
-        self._asked: List[Config] = []
-
-    def _tourney(self) -> Config:
-        idx = min(self.rng.sample(range(len(self.pop)),
-                                  min(self.strategy.tournament,
-                                      len(self.pop))),
-                  key=lambda i: self.fit[i])
-        return self.pop[idx]
-
-    def ask(self) -> List[Config]:
-        remaining = self._budget - self._rec.evaluations
-        if remaining <= 0:
-            return []
-        if self._initial is not None:
-            batch, self._initial = self._initial, None
-        else:
-            elite_i = min(range(len(self.pop)), key=lambda i: self.fit[i])
-            self._elite = (self.pop[elite_i], self.fit[elite_i])
-            batch = [self.strategy._offspring(self.space, self.rng,
-                                              self._tourney(),
-                                              self._tourney())
-                     for _ in range(self.strategy.population - 1)]
-        self._asked = batch[: int(min(remaining, len(batch)))]
-        return [dict(c) for c in self._asked]
-
-    def tell(self, results: List[Tuple[Config, float]]) -> None:
-        told = [(dict(cfg), float(t)) for cfg, t in results]
-        for cfg, t in told:
-            self._rec.add(cfg, t)
-        if self._elite is None:              # initial population
-            self.pop = [c for c, _ in told]
-            self.fit = [t for _, t in told]
-        else:
-            elite, elite_fit = self._elite
-            self.pop = [elite] + [c for c, _ in told]
-            self.fit = [elite_fit] + [t for _, t in told]
-
-    def result(self) -> SearchResult:
-        return SearchResult(self.strategy.name, self._rec.trials,
-                            self._rec.best, self._rec.evaluations,
-                            extra={"population": self.strategy.population,
-                                   "synchronous": True})
+            elite_i = min(range(len(pop)), key=lambda i: fit[i])
+            elite, elite_fit = [pop[elite_i]], [fit[elite_i]]
+            batch = [self._offspring(space, rng, tourney(), tourney())
+                     for _ in range(self.population - 1)]
+        return {"population": self.population}
 
 
 # ---------------------------------------------------------------------------

@@ -132,11 +132,8 @@ def test_registry_pluggable():
     class Fixed(Strategy):
         name = "fixed"
 
-        def run(self, space, objective, budget, seed=0):
-            from repro.core.strategies import _Recorder, SearchResult
-            rec = _Recorder(space, objective)
-            rec.evaluate(next(iter(space)))
-            return SearchResult("fixed", rec.trials, rec.best, 1)
+        def walk(self, space, budget, seed=0, seeds=None):
+            yield [next(iter(space))]
 
     if "fixed" not in available_strategies():
         register_strategy("fixed", Fixed)

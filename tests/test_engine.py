@@ -98,20 +98,6 @@ def test_memo_returns_identical_measurement():
 
 # -- ask/tell equivalence -----------------------------------------------------
 
-@pytest.mark.parametrize("strategy_factory", [
-    lambda: SimulatedAnnealing(),
-    lambda: ParticleSwarm(swarm_size=3),
-])
-def test_sequential_fallback_identical_to_direct_run(strategy_factory):
-    """Engine + sequential driver == strategy.run, trial for trial."""
-    sp = make_space()
-    direct = strategy_factory().run(sp, quadratic, 40, seed=7)
-    res, _, _ = run_engine(strategy_factory(), 40, seed=7, batching=False)
-    assert [t.time for t in res.trials] == [t.time for t in direct.trials]
-    assert [t.config for t in res.trials] == [t.config for t in direct.trials]
-    assert res.best_config == direct.best_config
-
-
 @pytest.mark.parametrize("name,kwargs", [
     ("pso", {"swarm_size": 3}),
     ("evolutionary", {"population": 6}),
@@ -126,8 +112,8 @@ def test_batched_drivers_deterministic_and_budgeted(name, kwargs):
 
 
 def test_batched_pso_matches_sequential_quality():
-    """Synchronous (batched) PSO must find the same optimum as the
-    sequential walk on an easy seeded space within the same budget."""
+    """PSO through the engine finds the same optimum as a direct run of
+    the same walk on an easy seeded space within the same budget."""
     direct = ParticleSwarm(swarm_size=3).run(make_space(), quadratic, 60,
                                              seed=0)
     res, _, _ = run_engine(ParticleSwarm(swarm_size=3), 60, seed=0)
@@ -221,6 +207,27 @@ def test_engine_trajectory_pinned(name, seeded):
     assert {k: res.extra[k] for k in want_extra} == want_extra
 
 
+class ReversingPredictor:
+    """Ranks every batch back to front, so the engine measures and tells
+    each batch in the reverse of the order it was asked."""
+
+    def rank(self, configs, shape, profile):
+        return list(range(len(configs), 0, -1))
+
+
+def test_walk_gets_times_in_asked_order_under_predictor_reordering():
+    plain, _, _ = run_engine(ParticleSwarm(swarm_size=3), 24, seed=11)
+    res, _, _ = run_engine(ParticleSwarm(swarm_size=3), 24, seed=11,
+                           predictor=ReversingPredictor())
+    assert res.extra["engine"]["predictor_rank_used"] > 0
+    # each particle keeps its own times: the reordering changes only the
+    # order of measurement, never the swarm's walk
+    assert res.extra["particle_traces"] == plain.extra["particle_traces"]
+    key = make_space().config_key
+    assert sorted(key(t.config) for t in res.trials) == \
+        sorted(key(t.config) for t in plain.trials)
+
+
 # -- early-stop pruning -------------------------------------------------------
 
 def test_median_prune_loop_semantics():
@@ -305,16 +312,11 @@ def test_custom_registered_strategy_works_via_fallback():
     class TwoStep(Strategy):
         name = "twostep"
 
-        def run(self, space, objective, budget, seed=0):
-            from repro.core.strategies import _Recorder
-            rec = _Recorder(space, objective)
+        def walk(self, space, budget, seed=0, seeds=None):
             import random as _random
             rng = _random.Random(seed)
             for _ in range(budget):
-                rec.evaluate(space.sample(rng))
-            from repro.core import SearchResult
-            return SearchResult(self.name, rec.trials, rec.best,
-                                rec.evaluations)
+                yield [space.sample(rng)]
 
     res, _, _ = run_engine(TwoStep(), 10)
     assert res.evaluations == 10 and res.best is not None

@@ -5,7 +5,7 @@ trials with FailureRecords), the retry policy, the max_failures circuit
 breaker, the typed-error contract of the built-in evaluators, and the
 regression tests for the satellite fixes that rode along (cache
 thread-safety + strict JSON, SA temperature-scale staleness,
-SequentialAskTell.close, sample_unique shortfall).
+AskTellDriver.close, sample_unique shortfall).
 """
 
 import json
@@ -18,8 +18,8 @@ import pytest
 from repro.core import (CacheEntry, CompileError, EngineConfig,
                         EvaluationEngine, Evaluator, FailureRecord,
                         KernelSpec, MeasureError, Measurement, RandomSearch,
-                        RetryPolicy, SearchSpace, SequentialAskTell,
-                        SimulatedAnnealing, TPUAnalyticalEvaluator,
+                        RetryPolicy, SearchSpace, SimulatedAnnealing,
+                        TPUAnalyticalEvaluator,
                         TransientError, Tuner, TuningCache,
                         VerificationFailure, WallClockEvaluator,
                         make_strategy)
@@ -418,7 +418,12 @@ def test_circuit_breaker_sequential_strategy_aborts():
     res, eng, _ = run_engine(SimulatedAnnealing(), 30, evaluator=Broken(),
                              max_failures=4)
     assert res.extra["engine"]["aborted"]
-    assert len(res.trials) == 4
+    # the walk's revisits are trials too; the breaker counts distinct
+    # configs, and the fourth one is the last trial
+    assert len(eng.failures) == 4
+    assert len({eng.space.config_key(t.config) for t in res.trials}) == 4
+    assert eng.space.config_key(res.trials[-1].config) not in {
+        eng.space.config_key(t.config) for t in res.trials[:-1]}
     assert res.strategy == "annealing"
 
 
@@ -633,24 +638,25 @@ def test_annealing_first_eval_inf_still_finds_optimum():
     assert math.isfinite(r.best_time)
 
 
-# -- satellite: SequentialAskTell.close ---------------------------------------
+# -- satellite: AskTellDriver.close ------------------------------------------
 
 def test_sequential_asktell_close_joins_thread_after_abort():
-    driver = SequentialAskTell(SimulatedAnnealing(), make_space(), 20, seed=0)
+    """close() mid-search ends the walk, is idempotent, and leaves
+    result() unavailable."""
+    driver = SimulatedAnnealing().asktell(make_space(), 20, seed=0)
     batch = driver.ask()
     assert len(batch) == 1
     driver.tell([(batch[0], 1.0)])
     driver.ask()                                # leave a tell pending
     driver.close()                              # abandon mid-search
-    assert not driver._thread.is_alive()
-    with pytest.raises(RuntimeError, match="closed before the search"):
+    assert driver.ask() == []
+    with pytest.raises(RuntimeError, match="before the search finished"):
         driver.result()
     driver.close()                              # idempotent
 
 
 def test_sequential_asktell_normal_completion_still_returns_result():
-    driver = SequentialAskTell(make_strategy("greedy"), make_space(), 5,
-                               seed=0)
+    driver = make_strategy("greedy").asktell(make_space(), 5, seed=0)
     while True:
         batch = driver.ask()
         if not batch:
@@ -659,7 +665,6 @@ def test_sequential_asktell_normal_completion_still_returns_result():
     res = driver.result()                       # finished naturally: fine
     assert res.evaluations == 5
     driver.close()
-    assert not driver._thread.is_alive()
     assert driver.result().evaluations == 5     # close after finish: no abort
 
 

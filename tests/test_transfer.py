@@ -350,22 +350,6 @@ def test_asktell_drivers_accept_seeds():
         driver.close()
 
 
-def test_engine_unbatched_path_still_seeds():
-    from repro.core import (EngineConfig, EvaluationEngine, KernelSpec,
-                            TPUAnalyticalEvaluator)
-    sp = _grid_space()
-    spec = KernelSpec(name="seedprobe", build=lambda cfg: (lambda: 0),
-                      analytical_model=lambda cfg, prof:
-                          cfg["A"] * cfg["B"] * 1e-6)
-    engine = EvaluationEngine(TPUAnalyticalEvaluator(noise_sigma=0.0), spec,
-                              sp, EngineConfig(batching=False, workers=1))
-    res = engine.run(make_strategy("pso", swarm_size=2), budget=4, seed=0,
-                     seeds=[{"A": 1, "B": 10}])
-    # batching=False routes through the base SequentialAskTell bridge into
-    # ParticleSwarm.run, which must still plant the seed as particle 0
-    assert res.trials[0].config == {"A": 1, "B": 10}
-
-
 def test_tune_kernel_warm_start_transfers_nearest(cache):
     k = _toy_kernel()
     cache.record(k.name, k.key_for({"N": 16}), "tpu_v5e", {"X": 8},
