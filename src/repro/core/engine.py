@@ -211,12 +211,17 @@ class EngineStats:
     # the evaluator's phases (PHASES), summed over the configs evaluated;
     # 0 for evaluators that report none.  A compile that raises loses its
     # phases with the artifact, a measure that raises loses its own.
-    args_s: float = 0.0             # building the trial's inputs
+    args_s: float = 0.0             # finding the trial's inputs (drawn
+                                    # once per kernel, shape and seed)
     lower_s: float = 0.0            # tracing and lowering the kernel
     xla_compile_s: float = 0.0      # the compiler
     first_call_s: float = 0.0       # the first call, under the device lock
-    verify_s: float = 0.0           # reference, host copy and comparison
+    verify_s: float = 0.0           # comparison on the device against
+                                    # the reference, run on the first trial
     timing_s: float = 0.0           # warm-up and timed samples
+    inputs_reused: int = 0          # trials whose inputs were the
+                                    # evaluator's held fixture, not drawn
+                                    # for them (counted beside the phases)
 
     @property
     def compile_overlap_ratio(self) -> float:
@@ -239,9 +244,11 @@ class EngineStats:
 
     def add_phases(self, seconds: Dict[str, float]) -> None:
         """Add an evaluator's phase seconds (``CompiledArtifact.stats`` or
-        ``Measurement.detail``) into the matching fields."""
+        ``Measurement.detail``), and the artifact's ``inputs_reused``, into
+        the matching fields."""
         for name in PHASES:
             setattr(self, name, getattr(self, name) + seconds.get(name, 0.0))
+        self.inputs_reused += int(seconds.get("inputs_reused", 0))
 
     def as_dict(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)

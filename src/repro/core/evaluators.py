@@ -272,11 +272,31 @@ def _failed(err: Exception | str, compile_s: float = 0.0) -> Measurement:
 
 
 @dataclasses.dataclass
+class _Fixture:
+    """One search's inputs and its reference's output, kept on the device
+    and shared by every trial of one kernel spec at one seed."""
+
+    spec: KernelSpec
+    seed: int
+    args: Tuple
+    ref: Any = None             # the reference's output, once a trial ran it
+    lock: threading.Lock = dataclasses.field(default_factory=threading.Lock)
+
+    def reference(self) -> Any:
+        """The reference's output on ``args``, run by the first caller."""
+        with self.lock:
+            if self.ref is None:
+                self.ref = self.spec.reference(*self.args)
+            return self.ref
+
+
+@dataclasses.dataclass
 class _CompiledKernel:
-    """Artifact of WallClockEvaluator.prepare: compiled fn, args, first output."""
+    """Artifact of WallClockEvaluator.prepare: compiled fn, the fixture
+    whose args it runs on, first output."""
 
     fn: Callable
-    args: Tuple
+    fixture: _Fixture
     out: Any
     compile_s: float
 
@@ -298,6 +318,17 @@ class WallClockEvaluator(Evaluator):
     and ``first_call_s`` in the artifact's ``stats``, ``verify_s`` and
     ``timing_s`` in the measurement's ``detail``.
 
+    The inputs, ``spec.make_args(np.random.default_rng(seed))``, are drawn
+    once per spec object and seed, and the reference is run once on them:
+    every trial of a search times and verifies against the same fixture,
+    held on the device, as CLTune's ``SetReference`` stores one reference
+    result.  Another spec (a new search's, or one whose reference was
+    replaced) or another seed replaces it, so the evaluator holds one
+    fixture at a time.  Kernels are compiled without donated arguments, so
+    no trial writes into the shared inputs.  A trial whose prepare found
+    the fixture held counts one ``inputs_reused`` in the artifact's
+    ``stats``.
+
     On a TPU every device run here holds :func:`device_lock`, so trials
     from concurrent engines (dtune thread workers, a background retune
     beside a serving loop) are timed one at a time.
@@ -313,17 +344,32 @@ class WallClockEvaluator(Evaluator):
         self.verify_outputs = verify_outputs
         self.seed = seed
         self.atol, self.rtol = atol, rtol
+        self._fixture: Optional[_Fixture] = None
+        self._fixture_lock = threading.Lock()
+
+    def _inputs(self, spec: KernelSpec) -> Tuple[_Fixture, bool]:
+        """The fixture of ``spec`` at this seed, drawn if it is not the one
+        held; and whether it was held.  Concurrent prepares draw it once."""
+        with self._fixture_lock:
+            if (self._fixture is not None and self._fixture.spec is spec
+                    and self._fixture.seed == self.seed):
+                return self._fixture, True
+            self._fixture = None    # free the old inputs before the draw
+            self._fixture = _Fixture(
+                spec, self.seed,
+                spec.make_args(np.random.default_rng(self.seed)))
+            return self._fixture, False
 
     def prepare(self, spec: KernelSpec, config: Config):
         if spec.make_args is None:
             raise CompileError("WallClockEvaluator requires spec.make_args")
-        rng = np.random.default_rng(self.seed)
         trial = spans.config_arg(config)
         seconds: Dict[str, float] = {}
         try:
             with spans.phase("repro.eval.args", seconds, "args_s",
                              config=trial):
-                args = spec.make_args(rng)
+                fixture, reused = self._inputs(spec)
+            args = fixture.args
             # compile outside the device lock (compiles overlap), run the
             # first call under it
             with spans.phase("repro.eval.lower", seconds, "lower_s",
@@ -340,13 +386,15 @@ class WallClockEvaluator(Evaluator):
             raise CompileError(f"{type(e).__name__}: {e}") from e
         compile_s = (seconds["lower_s"] + seconds["xla_compile_s"]
                      + seconds["first_call_s"])
-        kernel = _CompiledKernel(fn=fn, args=args, out=out, compile_s=compile_s)
+        kernel = _CompiledKernel(fn=fn, fixture=fixture, out=out,
+                                 compile_s=compile_s)
         return CompiledArtifact(
             kind=self.name,
             fingerprint=spec_fingerprint(spec.name, spec.meta, config,
                                          extra=f"seed={self.seed}"),
             profile="", payload=kernel,
-            stats=dict(seconds, compile_s=compile_s),
+            stats=dict(seconds, compile_s=compile_s,
+                       inputs_reused=int(reused)),
             compile_s=compile_s, persistable=False)
 
     def measure(self, spec: KernelSpec, config: Config,
@@ -365,7 +413,7 @@ class WallClockEvaluator(Evaluator):
     def _measure_locked(self, spec: KernelSpec, config: Config,
                         prepared: _CompiledKernel,
                         prune_threshold_s: Optional[float]) -> Measurement:
-        fn, args, out = prepared.fn, prepared.args, prepared.out
+        fn, args, out = prepared.fn, prepared.fixture.args, prepared.out
         compile_s = prepared.compile_s
         trial = spans.config_arg(config)
         seconds: Dict[str, float] = {}
@@ -375,8 +423,8 @@ class WallClockEvaluator(Evaluator):
             try:
                 with spans.phase("repro.eval.verify", seconds, "verify_s",
                                  config=trial):
-                    ref_out = spec.reference(*args)
-                    verify.assert_trees_close(out, ref_out,
+                    verify.assert_trees_close(out,
+                                              prepared.fixture.reference(),
                                               atol=self.atol, rtol=self.rtol)
                 verified = True
             except Exception as e:  # verification failure => config is invalid

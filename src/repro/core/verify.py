@@ -12,7 +12,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 # default absolute/relative tolerances per result dtype
 _TOLS = {
@@ -27,29 +26,47 @@ class VerificationError(AssertionError):
     pass
 
 
+@jax.jit
+def _errors(a, b):
+    """``max|a - b|`` (NaN ignored), ``max|b|`` and whether any element of
+    ``a - b`` is not finite, reduced where the arrays live."""
+    wide = jnp.promote_types(jnp.promote_types(a.dtype, b.dtype),
+                             jnp.float32)
+    d = a.astype(wide) - b.astype(wide)
+    return (jnp.nanmax(jnp.abs(d)), jnp.max(jnp.abs(b.astype(wide))),
+            ~jnp.all(jnp.isfinite(d)))
+
+
 def _leaf_close(a, b, atol: Optional[float], rtol: Optional[float]) -> None:
-    a = np.asarray(a)
-    b = np.asarray(b)
-    if a.shape != b.shape:
-        raise VerificationError(f"shape mismatch: {a.shape} vs {b.shape}")
-    if a.dtype != b.dtype:
-        # allow dtype promotion differences; compare in f32
-        a = a.astype(np.float32)
-        b = b.astype(np.float32)
-    da, dr = _TOLS.get(jnp.asarray(a).dtype, (1e-5, 1e-5))
-    atol = da if atol is None else atol
-    rtol = dr if rtol is None else rtol
+    """Normwise check of one leaf: ``max|a - b| <= atol + rtol * max|b|``,
+    failing on any NaN or inf in ``a - b``.
+
+    Both leaves become ``jax.Array``s (a host leaf is uploaded, a float64
+    one held as float32 unless x64 is on) and are reduced on the device to
+    three scalars; only those reach the host, where the rule is applied in
+    float64.  The difference is formed in the wider of the leaves' dtype
+    and float32 and rounded once, so a verdict can differ from an exact
+    one only where the error lies within one float32 rounding (2**-24
+    relative) of the threshold.  The tolerances are the leaves' dtype's,
+    or float32's where the two dtypes differ."""
     # normwise: rtol scales with the reference's largest magnitude.  An
     # output of a long reduction can sit near zero while its rounding error
     # scales with the terms summed; elementwise, two exact float32
     # summation orders of a 2048^3 GEMM already disagree on ~0.6% of
     # elements at rtol 1e-5.
-    a64, b64 = a.astype(np.float64), b.astype(np.float64)
-    err = np.abs(a64 - b64)
-    scale = float(np.abs(b64).max()) if b64.size else 0.0
-    if not np.all(err <= atol + rtol * scale):   # NaN anywhere fails too
+    a, b = jnp.asarray(a), jnp.asarray(b)
+    if a.shape != b.shape:
+        raise VerificationError(f"shape mismatch: {a.shape} vs {b.shape}")
+    if a.size == 0:
+        return
+    da, dr = _TOLS.get(a.dtype if a.dtype == b.dtype else jnp.float32.dtype,
+                       (1e-5, 1e-5))
+    atol = da if atol is None else atol
+    rtol = dr if rtol is None else rtol
+    err, scale, nonfinite = (float(x) for x in jax.device_get(_errors(a, b)))
+    if nonfinite or not err <= atol + rtol * scale:
         raise VerificationError(
-            f"output mismatch: max_abs_err={np.nanmax(err):.3e} "
+            f"output mismatch: max_abs_err={err:.3e} "
             f"max|ref|={scale:.3e} (atol={atol}, rtol={rtol} of max|ref|)")
 
 

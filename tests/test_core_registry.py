@@ -166,6 +166,29 @@ def test_builtin_kernels_registered():
         assert k.analytical_model is not None and k.make_args is not None
 
 
+@pytest.mark.parametrize("name,shape", [
+    ("gemm", {"M": 256, "N": 256, "K": 256, "dtype": "float32"}),
+    ("flash_attention", {"Sq": 128, "Sk": 128, "D": 64, "causal": True}),
+    ("conv2d", {"H": 256, "W": 256, "Fh": 3, "Fw": 3}),
+    ("moe_experts", {"N": 32, "d": 256, "m": 256, "E": 8, "E_held": 2,
+                     "k": 2, "dtype": "float32"}),
+])
+def test_builtin_make_args_is_a_function_of_the_seed(name, shape):
+    """A wall-clock search draws its inputs once per seed and reuses them
+    for every trial: that is sound only if a draw depends on nothing else."""
+    import jax
+    import numpy as np
+
+    k = resolve(name)
+    first = k.make_args(shape, np.random.default_rng(7))
+    again = k.make_args(shape, np.random.default_rng(7))
+    leaves = jax.tree_util.tree_leaves
+    assert len(leaves(first)) == len(leaves(again)) > 0
+    for a, b in zip(leaves(first), leaves(again)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_cache_env_override_and_clear(tmp_path, monkeypatch):
     target = str(tmp_path / "override" / "db.json")
     monkeypatch.setenv(_ENV_VAR, target)

@@ -177,16 +177,66 @@ def test_device_kind_lookup():
         attached_profile()                          # tests run on the host
 
 
-def test_verification_is_normwise():
+@pytest.mark.parametrize("leaf", [np.asarray, jnp.asarray],
+                         ids=["numpy", "jax"])
+def test_verification_is_normwise(leaf):
     """A long reduction's near-zero outputs carry rounding error on the
-    scale of its terms: that passes, an error on that scale fails."""
+    scale of its terms: that passes, an error on that scale fails.  Host
+    and device leaves are held to the same rule."""
     ref = np.array([100.0, 1e-3, -50.0], np.float32)
-    assert_trees_close(ref + np.float32(5e-4), ref)  # 5e-6 of max|ref|
+    # 5e-6 of max|ref| passes
+    assert_trees_close(leaf(ref + np.float32(5e-4)), leaf(ref))
     with pytest.raises(VerificationError, match="max_abs_err"):
-        assert_trees_close(ref + np.float32(5e-2), ref)
-    with pytest.raises(VerificationError):
-        assert_trees_close(np.array([np.nan, 1.0], np.float32),
-                           np.array([0.0, 1.0], np.float32))
+        assert_trees_close(leaf(ref + np.float32(5e-2)), leaf(ref))
+    for bad in (np.nan, np.inf):
+        with pytest.raises(VerificationError):
+            assert_trees_close(leaf(np.array([bad, 1.0], np.float32)),
+                               leaf(np.array([0.0, 1.0], np.float32)))
+
+
+def test_verification_catches_a_fault_when_the_inputs_are_reused():
+    """The faulty configuration is the second trial, so its inputs and the
+    reference's output are the ones the first trial left."""
+    t = Tuner(evaluator=WallClockEvaluator(repeats=1))
+    t.set_reference(lambda x: x)
+    t.add_kernel(_buggy_builder, name="buggy", make_args=_make_args)
+    t.add_parameter("WPT", [1, 4, 2])
+    # one compile at a time: the first trial draws the inputs
+    out = t.tune(strategy="full", engine={"workers": 1})
+    assert [tr.config["WPT"] for tr in out.result.trials] == [1, 4, 2]
+    bad = out.result.trials[1]
+    assert not bad.ok and bad.failure.error_type == "VerificationFailure"
+    assert out.best_config["WPT"] != 4
+    # the faulty trial counts too: its verify ran, and failed, on the fixture
+    assert out.engine_stats["inputs_reused"] == 2
+
+
+def test_verification_follows_a_reference_set_after_a_search():
+    """A reference set between two searches on one evaluator replaces the
+    held reference output: the second search runs the new reference, and a
+    configuration that matches only the old one fails."""
+    ev = WallClockEvaluator(repeats=1)
+    ran = []
+
+    def reference(scale):
+        def ref(x):
+            ran.append(scale)
+            return x * scale
+        return ref
+
+    t = Tuner(evaluator=ev)
+    t.set_reference(reference(1.0))
+    t.add_kernel(lambda cfg: (lambda x: x * cfg["S"]), name="scale",
+                 make_args=_make_args)
+    t.add_parameter("S", [1.0, 2.0])
+    first = t.tune(strategy="full", engine={"workers": 1})
+    assert first.best_config == {"S": 1.0} and ran == [1.0]
+    t.set_reference(reference(2.0))
+    second = t.tune(strategy="full", engine={"workers": 1})
+    assert ran == [1.0, 2.0]
+    assert second.best_config == {"S": 2.0}
+    [old] = [tr for tr in second.result.trials if tr.config["S"] == 1.0]
+    assert not old.ok and old.failure.error_type == "VerificationFailure"
 
 
 @pytest.mark.parametrize("tpu", [False, True])
@@ -196,7 +246,7 @@ def test_wallclock_trials_time_one_at_a_time_on_tpu(monkeypatch, tpu):
     import threading
     import time
 
-    from repro.core.evaluators import _CompiledKernel
+    from repro.core.evaluators import _CompiledKernel, _Fixture
     monkeypatch.setattr(evaluators_mod, "on_tpu", lambda: tpu)
     lock = threading.Lock()
     active, peak = [0], [0]
@@ -216,8 +266,9 @@ def test_wallclock_trials_time_one_at_a_time_on_tpu(monkeypatch, tpu):
 
     def trial():
         barrier.wait()
-        ev.measure(spec, {}, _CompiledKernel(fn=kernel, args=(), out=None,
-                                             compile_s=0.0))
+        ev.measure(spec, {}, _CompiledKernel(
+            fn=kernel, fixture=_Fixture(spec, 0, ()), out=None,
+            compile_s=0.0))
     threads = [threading.Thread(target=trial) for _ in range(2)]
     for t in threads:
         t.start()

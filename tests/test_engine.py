@@ -380,6 +380,125 @@ def test_wallclock_search_fills_phase_counters(tmp_path):
     assert 0.9 * s["measure_total_s"] <= measure <= s["measure_total_s"] + 1e-5
 
 
+def _counted_copy_tuner(evaluator, calls, n=64):
+    """A copy kernel of length ``n`` with three configurations, its input
+    draws and reference runs counted in ``calls``."""
+    import jax.numpy as jnp
+
+    from repro.core import Tuner
+
+    def make_args(rng):
+        calls["make_args"] += 1
+        return (jnp.asarray(rng.normal(size=n), jnp.float32),)
+
+    def reference(x):
+        calls["reference"] += 1
+        return x
+
+    t = Tuner(evaluator=evaluator)
+    t.set_reference(reference)
+    t.add_kernel(lambda cfg: (lambda x: x.reshape(-1, cfg["W"]).reshape(n)),
+                 name="copy", make_args=make_args, meta={"n": n})
+    t.add_parameter("W", [1, 2, 4])
+    return t
+
+
+def _search(tuner):
+    # one compile at a time: the first trial draws the inputs
+    return tuner.tune(strategy="full", engine={"workers": 1}).engine_stats
+
+
+def test_wallclock_search_draws_inputs_and_runs_reference_once():
+    import collections
+
+    from repro.core import WallClockEvaluator
+    ev = WallClockEvaluator(repeats=2, seed=3)
+    calls = collections.Counter()
+    s = _search(_counted_copy_tuner(ev, calls))
+    assert s["unique_configs"] == 3
+    assert calls == {"make_args": 1, "reference": 1}
+    assert s["inputs_reused"] == 2
+    # no trial wrote into the inputs every trial shared
+    [x] = ev._fixture.args
+    np.testing.assert_array_equal(
+        np.asarray(x),
+        np.random.default_rng(3).normal(size=64).astype(np.float32))
+
+
+def test_wallclock_fixture_follows_the_spec_and_the_seed():
+    import collections
+
+    from repro.core import WallClockEvaluator
+    ev = WallClockEvaluator(repeats=1)
+    calls = collections.Counter()
+    t = _counted_copy_tuner(ev, calls)
+    _search(t)
+    # the same spec and seed again: every trial reuses the fixture
+    assert _search(t)["inputs_reused"] == 3
+    assert calls == {"make_args": 1, "reference": 1}
+    # another spec of the same name and shape draws and runs its own
+    assert _search(_counted_copy_tuner(ev, calls))["inputs_reused"] == 2
+    assert calls == {"make_args": 2, "reference": 2}
+    # so does a new shape
+    t = _counted_copy_tuner(ev, calls, n=128)
+    assert _search(t)["inputs_reused"] == 2
+    assert calls == {"make_args": 3, "reference": 3}
+    # and a new seed, and the evaluator holds only the newest fixture
+    ev.seed = 1
+    assert _search(t)["inputs_reused"] == 2
+    assert calls == {"make_args": 4, "reference": 4}
+    assert ev._fixture.seed == 1 and ev._fixture.spec.meta == {"n": 128}
+
+
+def test_wallclock_fixture_is_drawn_and_run_once_under_contention():
+    """Concurrent prepares (a compile pool, dtune thread workers) draw the
+    inputs once, and concurrent measures run the reference once."""
+    import sys
+    import threading
+
+    import jax.numpy as jnp
+
+    from repro.core import WallClockEvaluator
+    calls = {"make_args": 0, "reference": 0}
+
+    def make_args(rng):
+        calls["make_args"] += 1
+        return (jnp.asarray(rng.normal(size=8), jnp.float32),)
+
+    def reference(x):
+        calls["reference"] += 1
+        return x
+
+    spec = KernelSpec(name="copy", build=lambda cfg: (lambda x: x),
+                      make_args=make_args, reference=reference)
+    ev = WallClockEvaluator(repeats=1)
+    n = 16
+    barrier = threading.Barrier(n)
+    artifacts = [None] * n
+
+    def trial(i):
+        barrier.wait()
+        artifacts[i] = ev.prepare(spec, {"i": i})
+        barrier.wait()
+        ev.measure(spec, {"i": i}, artifacts[i])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=trial, args=(i,))
+                   for i in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert calls == {"make_args": 1, "reference": 1}
+    assert len({id(a.payload.fixture) for a in artifacts}) == 1
+    assert sum(a.stats["inputs_reused"] for a in artifacts) == n - 1
+
+
 def test_wallclock_search_spans_reach_the_profiler_trace(tmp_path):
     import collections
     import glob
